@@ -12,10 +12,13 @@ Grid: one program per row-tile of tokens; the projection weight is small
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels import resolve_interpret
 
 
 DEFAULT_BLOCK_T = 128
@@ -30,7 +33,7 @@ def _encode_kernel(x_ref, w_ref, codes_ref, scales_ref):
 
 
 def encode_call(x: jax.Array, w_enc: jax.Array, *, block_t: int = DEFAULT_BLOCK_T,
-                interpret: bool = True):
+                interpret: Optional[bool] = None):
     """x (T, d) [T % block_t == 0], w_enc (d, r)."""
     T, d = x.shape
     r = w_enc.shape[1]
@@ -50,7 +53,7 @@ def encode_call(x: jax.Array, w_enc: jax.Array, *, block_t: int = DEFAULT_BLOCK_
             jax.ShapeDtypeStruct((T, r), jnp.int8),
             jax.ShapeDtypeStruct((T, 1), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x, w_enc)
 
 
@@ -62,7 +65,7 @@ def _decode_kernel(codes_ref, scales_ref, w_ref, out_ref, *, out_dtype):
 
 def decode_call(codes: jax.Array, scales: jax.Array, w_dec: jax.Array,
                 out_dtype=jnp.float32, *, block_t: int = DEFAULT_BLOCK_T,
-                interpret: bool = True):
+                interpret: Optional[bool] = None):
     """codes (T, r) int8, scales (T, 1), w_dec (r, d)."""
     T, r = codes.shape
     d = w_dec.shape[1]
@@ -77,5 +80,5 @@ def decode_call(codes: jax.Array, scales: jax.Array, w_dec: jax.Array,
         ],
         out_specs=pl.BlockSpec((block_t, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((T, d), jnp.dtype(out_dtype)),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(codes, scales, w_dec)

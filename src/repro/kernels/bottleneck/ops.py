@@ -1,5 +1,5 @@
-"""Jit'd wrappers for the bottleneck kernels: handle (B, S, d) batching,
-token-count padding to the row-tile, and CPU interpret mode."""
+"""Jit'd wrappers for the bottleneck kernels: handle (B, S, d) batching
+and token-count padding to the row-tile."""
 from __future__ import annotations
 
 import functools
@@ -9,8 +9,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.bottleneck import bottleneck as _k
-
-_INTERPRET = True  # CPU container: interpret mode; flip on real TPU.
 
 
 def _flatten(x):
@@ -33,8 +31,7 @@ def bottleneck_encode(x: jax.Array, w_enc: jax.Array,
     """x (..., d) -> (codes int8 (..., r), scales f32 (..., 1))."""
     flat, lead = _flatten(x)
     flat, T = _pad_rows(flat, block_t)
-    codes, scales = _k.encode_call(flat, w_enc, block_t=block_t,
-                                   interpret=_INTERPRET)
+    codes, scales = _k.encode_call(flat, w_enc, block_t=block_t)
     r = w_enc.shape[1]
     return (codes[:T].reshape(*lead, r),
             scales[:T].reshape(*lead, 1))
@@ -49,5 +46,5 @@ def bottleneck_decode(codes: jax.Array, scales: jax.Array, w_dec: jax.Array,
     flat, T = _pad_rows(flat, block_t)
     sflat, _ = _pad_rows(sflat, block_t)
     out = _k.decode_call(flat, sflat, w_dec, out_dtype=out_dtype,
-                         block_t=block_t, interpret=_INTERPRET)
+                         block_t=block_t)
     return out[:T].reshape(*lead, w_dec.shape[1])

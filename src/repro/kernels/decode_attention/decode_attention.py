@@ -24,11 +24,14 @@ verify positions (the whole point of multi-token verification).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -47,7 +50,7 @@ def _decode_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, m_scr, l_scr,
     k = k_ref[0].astype(jnp.float32)                   # (bk, hd)
     v = v_ref[0].astype(jnp.float32)
     s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale  # (1, bk)
-    s = s + bias_ref[0].astype(jnp.float32)[None, :]
+    s = s + bias_ref[0].astype(jnp.float32)            # (1, bk)
     m_prev, l_prev = m_scr[...], l_scr[...]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
     p = jnp.exp(s - m_new)
@@ -65,7 +68,7 @@ def _decode_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, m_scr, l_scr,
 
 def paged_decode_call(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                       page_table: jax.Array, bias: jax.Array, *, group: int,
-                      interpret: bool = True) -> jax.Array:
+                      interpret: Optional[bool] = None) -> jax.Array:
     """Page-table-aware gather path: the KV cache lives in a shared page
     pool and each batch row addresses it through its page table.
 
@@ -80,13 +83,16 @@ def paged_decode_call(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     BlockSpec index maps dereference it *before* the kernel body runs —
     each page streams HBM->VMEM exactly once per (row, head) program,
     the same online-softmax traffic floor as the contiguous kernel; only
-    the addressing is indirect.
+    the addressing is indirect. The bias is viewed as
+    (B, n_pages, 1, page) so each block's last two dims span the array's
+    (the TPU tiling rule for a page narrower than 128 lanes).
     """
     BH, _, hd = q.shape
     page = k_pool.shape[2]
     B, n_pages = page_table.shape
     heads_per_batch = BH // B
     scale = 1.0 / (hd ** 0.5)
+    bias = bias.reshape(B, n_pages, 1, page)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(BH, n_pages),
@@ -100,8 +106,8 @@ def paged_decode_call(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                 (1, 1, page, hd),
                 lambda h, ki, pt: ((h % heads_per_batch) // group,
                                    pt[h // heads_per_batch, ki], 0, 0)),
-            pl.BlockSpec((1, page),
-                         lambda h, ki, pt: (h // heads_per_batch, ki)),
+            pl.BlockSpec((1, 1, 1, page),
+                         lambda h, ki, pt: (h // heads_per_batch, ki, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, hd), lambda h, ki, pt: (h, 0, 0)),
         scratch_shapes=[
@@ -115,7 +121,7 @@ def paged_decode_call(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((BH, 1, hd), q.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(page_table, q, k_pool, v_pool, bias)
 
 
@@ -139,7 +145,7 @@ def _paged_decode_kernel(pt_ref, q_ref, k_ref, v_ref, bias_ref, o_ref,
     k = k_ref[0, 0].astype(jnp.float32)                # (page, hd)
     v = v_ref[0, 0].astype(jnp.float32)
     s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-    s = s + bias_ref[0].astype(jnp.float32)[None, :]
+    s = s + bias_ref[0, 0].astype(jnp.float32)         # (1, page)
     m_prev, l_prev = m_scr[...], l_scr[...]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
     p = jnp.exp(s - m_new)
@@ -157,7 +163,7 @@ def _paged_decode_kernel(pt_ref, q_ref, k_ref, v_ref, bias_ref, o_ref,
 
 def paged_verify_call(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                       page_table: jax.Array, bias: jax.Array, *, group: int,
-                      interpret: bool = True) -> jax.Array:
+                      interpret: Optional[bool] = None) -> jax.Array:
     """Multi-query paged attention for the speculative verify step.
 
     q (BH, C, hd) — C chunk tokens per (row, head) program, laid out
@@ -170,13 +176,16 @@ def paged_verify_call(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     Grid (BH, n_pages), cache-innermost: each page streams HBM->VMEM
     once per (row, head) and all C verify positions score against it
     before the next page loads — the (C, 1)/(C, hd) running statistics
-    live in VMEM scratch exactly like the single-query kernel's.
+    live in VMEM scratch exactly like the single-query kernel's. The
+    bias is laid out page-major, (B, n_pages, C, page), so each block's
+    last two dims span the array's.
     """
     BH, C, hd = q.shape
     page = k_pool.shape[2]
     B, n_pages = page_table.shape
     heads_per_batch = BH // B
     scale = 1.0 / (hd ** 0.5)
+    bias = bias.reshape(B, C, n_pages, page).transpose(0, 2, 1, 3)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(BH, n_pages),
@@ -190,8 +199,8 @@ def paged_verify_call(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                 (1, 1, page, hd),
                 lambda h, ki, pt: ((h % heads_per_batch) // group,
                                    pt[h // heads_per_batch, ki], 0, 0)),
-            pl.BlockSpec((1, C, page),
-                         lambda h, ki, pt: (h // heads_per_batch, 0, ki)),
+            pl.BlockSpec((1, 1, C, page),
+                         lambda h, ki, pt: (h // heads_per_batch, ki, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, C, hd), lambda h, ki, pt: (h, 0, 0)),
         scratch_shapes=[
@@ -205,7 +214,7 @@ def paged_verify_call(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((BH, C, hd), q.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(page_table, q, k_pool, v_pool, bias)
 
 
@@ -227,7 +236,7 @@ def _paged_verify_kernel(pt_ref, q_ref, k_ref, v_ref, bias_ref, o_ref,
     k = k_ref[0, 0].astype(jnp.float32)                # (page, hd)
     v = v_ref[0, 0].astype(jnp.float32)
     s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-    s = s + bias_ref[0].astype(jnp.float32)            # (C, page)
+    s = s + bias_ref[0, 0].astype(jnp.float32)         # (C, page)
     m_prev, l_prev = m_scr[...], l_scr[...]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
     p = jnp.exp(s - m_new)
@@ -245,10 +254,12 @@ def _paged_verify_kernel(pt_ref, q_ref, k_ref, v_ref, bias_ref, o_ref,
 
 def decode_call(q: jax.Array, k: jax.Array, v: jax.Array, bias: jax.Array,
                 *, group: int, block_k: int = 512,
-                interpret: bool = True) -> jax.Array:
+                interpret: Optional[bool] = None) -> jax.Array:
     """q (BH, 1, hd); k/v (BK, W, hd); bias (B, W). BH = B*H laid out
     kv-major so query row p reads kv row p // group and bias row
-    p // (H) — H passed implicitly via bias grid math below."""
+    p // (H) — H passed implicitly via bias grid math below. The bias is
+    viewed as (B, 1, W) so a (1, 1, block_k) block spans the array's
+    second-to-last dim."""
     BH, _, hd = q.shape
     BK, W, _ = k.shape
     assert W % block_k == 0, (W, block_k)
@@ -256,6 +267,7 @@ def decode_call(q: jax.Array, k: jax.Array, v: jax.Array, bias: jax.Array,
     B = bias.shape[0]
     heads_per_batch = BH // B
     scale = 1.0 / (hd ** 0.5)
+    bias = bias.reshape(B, 1, W)
     kernel = functools.partial(_decode_kernel, scale=scale, num_kv_blocks=nk)
     return pl.pallas_call(
         kernel,
@@ -264,8 +276,8 @@ def decode_call(q: jax.Array, k: jax.Array, v: jax.Array, bias: jax.Array,
             pl.BlockSpec((1, 1, hd), lambda h, ki: (h, 0, 0)),
             pl.BlockSpec((1, block_k, hd), lambda h, ki: (h // group, ki, 0)),
             pl.BlockSpec((1, block_k, hd), lambda h, ki: (h // group, ki, 0)),
-            pl.BlockSpec((1, block_k),
-                         lambda h, ki: (h // heads_per_batch, ki)),
+            pl.BlockSpec((1, 1, block_k),
+                         lambda h, ki: (h // heads_per_batch, 0, ki)),
         ],
         out_specs=pl.BlockSpec((1, 1, hd), lambda h, ki: (h, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, 1, hd), q.dtype),
@@ -274,5 +286,5 @@ def decode_call(q: jax.Array, k: jax.Array, v: jax.Array, bias: jax.Array,
             pltpu.VMEM((1, 1), jnp.float32),
             pltpu.VMEM((1, hd), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v, bias)

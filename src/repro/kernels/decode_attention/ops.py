@@ -6,11 +6,9 @@ paged pool shards kv-heads over the mesh's "model" axis, so inside a
 ``K = n_kv_heads / model_shards`` (and ``H = num_heads / model_shards``)
 — the ``group``/``heads_per_batch`` grid math is derived from the
 per-shard shapes, so the kernel bodies run unchanged on the smaller K.
-On this CPU container the kernels execute in *interpret mode* and
-cannot lower inside a GSPMD partition, so ``ShardedServingContext``
-serves the jnp reference attention instead (XLA partitions it over the
-head-sharded operands); route the kernels through ``shard_map`` with
-the per-shard head counts on real TPU.
+A ``pallas_call`` is not partitioned by GSPMD, so until the kernels run
+under ``shard_map`` ``ShardedServingContext`` serves the jnp reference
+attention instead (XLA partitions it over the head-sharded operands).
 """
 from __future__ import annotations
 
@@ -21,7 +19,6 @@ import jax.numpy as jnp
 
 from repro.kernels.decode_attention import decode_attention as _k
 
-_INTERPRET = True  # CPU container: interpret mode; flip on real TPU.
 NEG_INF = -1e30
 
 
@@ -41,8 +38,7 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     qh = q.reshape(B, K, G, hd).reshape(B * H, 1, hd)
     kh = k.transpose(0, 2, 1, 3).reshape(B * K, W + pad, hd)
     vh = v.transpose(0, 2, 1, 3).reshape(B * K, W + pad, hd)
-    out = _k.decode_call(qh, kh, vh, bias, group=G, block_k=block_k,
-                         interpret=_INTERPRET)
+    out = _k.decode_call(qh, kh, vh, bias, group=G, block_k=block_k)
     return out.reshape(B, K, G, hd).reshape(B, H, hd)
 
 
@@ -67,7 +63,7 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     vh = v_pool.transpose(2, 0, 1, 3)
     out = _k.paged_decode_call(qh, kh, vh,
                                jnp.asarray(page_table, jnp.int32), bias,
-                               group=G, interpret=_INTERPRET)
+                               group=G)
     return out.reshape(B, K, G, hd).reshape(B, H, hd)
 
 
@@ -95,6 +91,6 @@ def paged_verify_attention(q: jax.Array, k_pool: jax.Array,
     vh = v_pool.transpose(2, 0, 1, 3)
     out = _k.paged_verify_call(qh, kh, vh,
                                jnp.asarray(page_table, jnp.int32), bias,
-                               group=G, interpret=_INTERPRET)
+                               group=G)
     return out.reshape(B, K, G, C, hd).reshape(B, H, C, hd) \
               .transpose(0, 2, 1, 3)

@@ -13,11 +13,14 @@ masked kv blocks are skipped via pl.when on the block index.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -70,7 +73,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
 def flash_call(q: jax.Array, k: jax.Array, v: jax.Array, *, causal: bool,
                block_q: int = 128, block_k: int = 128,
-               valid_len: int = -1, interpret: bool = True) -> jax.Array:
+               valid_len: int = -1,
+               interpret: Optional[bool] = None) -> jax.Array:
     """q (BH, S, hd), k/v (BK, S, hd), BH % BK == 0 (grouped heads laid out
     so that query row p maps to kv row p // group)."""
     BH, S, hd = q.shape
@@ -100,5 +104,5 @@ def flash_call(q: jax.Array, k: jax.Array, v: jax.Array, *, causal: bool,
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, hd), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v)

@@ -1,5 +1,5 @@
 """Jit'd wrapper: (B,S,H,hd) layout -> kernel layout, GQA head grouping,
-sequence padding, CPU interpret mode."""
+sequence padding."""
 from __future__ import annotations
 
 import functools
@@ -8,8 +8,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.flash_attention import flash_attention as _k
-
-_INTERPRET = True  # CPU container: interpret mode; flip on real TPU.
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k"))
@@ -40,7 +38,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     kh = kp.transpose(0, 2, 1, 3).reshape(B * K, Sp, hd)
     vh = vp.transpose(0, 2, 1, 3).reshape(B * K, Sp, hd)
     out = _k.flash_call(qh, kh, vh, causal=causal, block_q=block_q,
-                        block_k=block_k, valid_len=S, interpret=_INTERPRET)
+                        block_k=block_k, valid_len=S)
     out = out.reshape(B, K, G, Sp, hd).transpose(0, 3, 1, 2, 4) \
         .reshape(B, Sp, H, hd)
     return out[:, :S]

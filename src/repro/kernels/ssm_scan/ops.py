@@ -9,8 +9,6 @@ import jax.numpy as jnp
 
 from repro.kernels.ssm_scan import ssm_scan as _k
 
-_INTERPRET = True  # CPU container: interpret mode; flip on real TPU.
-
 
 @functools.partial(jax.jit, static_argnames=("chunk", "block_c"))
 def chunked_scan(decay: jax.Array, drive: jax.Array, chunk: int = 64,
@@ -26,6 +24,5 @@ def chunked_scan(decay: jax.Array, drive: jax.Array, chunk: int = 64,
         pads = ((0, 0), (0, pad_s), (0, pad_c), (0, 0))
         decay = jnp.pad(decay, pads)
         drive = jnp.pad(drive, pads)
-    out = _k.scan_call(decay, drive, chunk=chunk, block_c=block_c,
-                       interpret=_INTERPRET)
+    out = _k.scan_call(decay, drive, chunk=chunk, block_c=block_c)
     return out[:, :S, :C]

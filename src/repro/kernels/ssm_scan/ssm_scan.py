@@ -13,11 +13,14 @@ recurrence over lanes of (channels x state) held in vector registers
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import resolve_interpret
 
 
 def _scan_kernel(decay_ref, drive_ref, h_ref, state_scr, *, chunk: int):
@@ -39,7 +42,8 @@ def _scan_kernel(decay_ref, drive_ref, h_ref, state_scr, *, chunk: int):
 
 
 def scan_call(decay: jax.Array, drive: jax.Array, *, chunk: int = 64,
-              block_c: int = 128, interpret: bool = True) -> jax.Array:
+              block_c: int = 128,
+              interpret: Optional[bool] = None) -> jax.Array:
     """decay/drive (B, S, C, N); S % chunk == 0, C % block_c == 0."""
     B, S, C, N = decay.shape
     grid = (B, C // block_c, S // chunk)
@@ -52,5 +56,5 @@ def scan_call(decay: jax.Array, drive: jax.Array, *, chunk: int = 64,
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((B, S, C, N), jnp.float32),
         scratch_shapes=[pltpu.VMEM((block_c, N), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(decay, drive)

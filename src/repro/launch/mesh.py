@@ -16,12 +16,21 @@ inter-pod link carries exactly the compressed boundary payload.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _mesh(shape, axes) -> jax.sharding.Mesh:
+    """``jax.make_mesh`` with ``Auto`` axes: sharding propagates through
+    GSPMD as the key-path rules and ``in/out_shardings`` direct, rather
+    than being part of every array's type (JAX's ``Explicit`` default)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def make_local_mesh(model: int = 1) -> jax.sharding.Mesh:
@@ -39,4 +48,4 @@ def make_local_mesh(model: int = 1) -> jax.sharding.Mesh:
             f"model={model} does not divide the {n} local devices; pick a "
             f"divisor of {n} (or force more host devices via "
             f"XLA_FLAGS=--xla_force_host_platform_device_count=N)")
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return _mesh((n // model, model), ("data", "model"))

@@ -165,7 +165,7 @@ def serve_dryrun() -> None:
             inner, mesh=mesh,
             in_specs=(P(("data",)), P(("data",))),
             out_specs=P(("data",)),
-            check_rep=False)(images, query)
+            check_vma=False)(images, query)
 
     with mesh:
         lowered = jax.jit(split_serve).lower(aparams, abn, images, query)
@@ -198,6 +198,8 @@ def main() -> None:
                     help="cloud serving discipline: closed microbatches or "
                          "token-level in-flight batching")
     args = ap.parse_args()
+    from repro.launch.cache import use_compile_cache
+    use_compile_cache()
     if args.dryrun:
         serve_dryrun()
     else:

@@ -83,6 +83,8 @@ def main() -> None:
     ap.add_argument("--lr", type=float, default=3e-4)
     ap.add_argument("--out", default="benchmarks/artifacts/checkpoints")
     args = ap.parse_args()
+    from repro.launch.cache import use_compile_cache
+    use_compile_cache()
     if args.lisa:
         train_lisa_system(args.steps, args.bn_steps, args.ft_steps, args.out)
     elif args.arch:

@@ -210,9 +210,9 @@ def gqa_decode_paged(p: dict, cfg: ModelConfig, x: jax.Array,
     einsums stay shard-local per head slice (each shard sees
     K / model_shards kv heads) and the only collective is the
     all-reduce after the row-parallel ``wo``. The flash kernel path is
-    per-shard-head-count-ready but needs ``shard_map`` (it cannot lower
-    inside a GSPMD partition in interpret mode), so sharded contexts
-    pin ``use_flash_decode=False`` — see ``kernels/decode_attention``.
+    per-shard-head-count-ready but needs ``shard_map`` (GSPMD does not
+    partition a ``pallas_call``), so sharded contexts pin
+    ``use_flash_decode=False`` — see ``kernels/decode_attention``.
     """
     B, S, d = x.shape
     H, K, hd = cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim

@@ -175,18 +175,14 @@ def cache_mask(cache_positions: jax.Array, pos: jax.Array,
 
 
 def wsc(x, *spec_axes):
-    """with_sharding_constraint if a mesh context is active; no-op
-    otherwise. "BATCH" resolves to the mesh's batch axes."""
-    try:
-        import jax
-        from jax._src import mesh as mesh_lib
-        m = mesh_lib.thread_resources.env.physical_mesh
-        if m.empty or "model" not in m.axis_names:
-            return x
-        from jax.sharding import NamedSharding, PartitionSpec as P
-        batch = tuple(a for a in ("pod", "data") if a in m.axis_names)
-        axes = tuple(batch if a == "BATCH" else a for a in spec_axes)
-        return jax.lax.with_sharding_constraint(
-            x, NamedSharding(m, P(*axes)))
-    except Exception:  # noqa: BLE001
+    """with_sharding_constraint if a mesh context with a "model" axis is
+    active; no-op otherwise. "BATCH" resolves to the mesh's batch axes.
+    Under a mesh, a constraint that cannot apply raises."""
+    from jax._src import mesh as mesh_lib
+    m = mesh_lib.thread_resources.env.physical_mesh
+    if m.empty or "model" not in m.axis_names:
         return x
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    batch = tuple(a for a in ("pod", "data") if a in m.axis_names)
+    axes = tuple(batch if a == "BATCH" else a for a in spec_axes)
+    return jax.lax.with_sharding_constraint(x, NamedSharding(m, P(*axes)))

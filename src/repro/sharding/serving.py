@@ -29,12 +29,15 @@ The decode/verify **Pallas kernels** have a per-shard head-count path:
 under ``shard_map`` each shard would run the kernel on
 ``n_kv_heads / mesh.shape["model"]`` heads (the ``group`` and
 ``heads_per_batch`` grid math is already per-shard-shape-driven, so the
-kernel body needs no change — only smaller K). On this container the
-kernels execute in *interpret mode* and cannot lower inside a GSPMD
-partition, so the sharded context pins ``use_flash_decode=False`` and
-serves the jnp reference attention, which XLA partitions automatically
-(one all-reduce after the row-parallel output projection per layer);
-flip the kernel path on under ``shard_map`` on real TPU.
+kernel body needs no change — only smaller K). GSPMD does not partition
+a ``pallas_call``, so until the kernels run under ``shard_map`` the
+sharded context pins ``use_flash_decode=False`` and serves the jnp
+reference attention, which XLA partitions automatically (one all-reduce
+after the row-parallel output projection per layer).
+
+Weights built already sharded by the same key-path rules (a jitted init
+with those ``out_shardings``) are used as they are: no chip ever holds
+the whole model.
 
 Exactness: sharding only changes *where* each head's arithmetic runs
 and the reduction order of the output-projection sum, not the
@@ -81,9 +84,8 @@ class ShardedServingContext:
         self.page_size = executor.page_size
         self.max_new_tokens = executor.max_new_tokens
         self.lut = executor.lut
-        # the Pallas kernels cannot lower inside a GSPMD partition on
-        # this container (interpret mode); serve the jnp attention ref,
-        # which XLA partitions over the head-sharded operands
+        # GSPMD does not partition a pallas_call: serve the jnp attention
+        # ref, which XLA partitions over the head-sharded operands
         self.flash_decode = False
         self._gen_pcfg = dataclasses.replace(
             self.pcfg, llm=self.pcfg.llm.replace(use_flash_decode=False))

@@ -7,6 +7,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.launch.mesh import make_local_mesh
 from repro.models import (ModelConfig, MoEConfig, forward, init_cache,
                           decode_step, init_params)
 
@@ -90,7 +91,7 @@ def test_kvhd_decode_consistency_with_mesh():
     params = init_params(cfg, jax.random.PRNGKey(0))
     tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 8), 0, 97)
     base, *_ = forward(params, cfg, {"tokens": tokens})
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_local_mesh(model=1)
     cache = init_cache(cfg, 2, 8)
     outs = []
     with mesh:

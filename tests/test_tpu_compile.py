@@ -1,0 +1,86 @@
+"""The serving path's Pallas kernels compile for a TPU v5e at lisa-7b
+widths (hd 128, 32 kv heads, page 16, bf16 pool, up to 16 slots, a
+4-token verify chunk).
+
+The chip is described, not attached: the TPU compiler lowers each kernel
+with ``interpret=False`` for one device of a ``v5e:2x2`` topology, which
+refuses what Mosaic would refuse on the chip (block shapes off the
+tiling, VMEM overuse). The topology is described inside a module-scoped
+fixture only -- never at import -- so every pytest worker collects the
+same tests and only the worker that runs them loads the TPU library."""
+import functools
+import importlib
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+HD, KV_HEADS, PAGE, SLOTS, CHUNK = 128, 32, 16, 16, 4
+POOL_PAGES, TABLE_PAGES = 128, 14          # 13 prefix pages + 1 decode
+DRAFT_WIDTH = 211                          # 196 CLIP + 8 query + 4 + 3
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip cannot be read back from the
+    # persistent cache without one: keep these compiles out of it
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", enabled)
+    compilation_cache.reset_cache()
+
+
+def _kernels(name):
+    return importlib.import_module(f"repro.kernels.{name}.{name}")
+
+
+def _compile(sharding, fn, *shapes):
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=sharding)
+            for s, dt in shapes]
+    return jax.jit(fn).lower(*args).compile()
+
+
+def _cases():
+    bf, f32, i32 = jnp.bfloat16, jnp.float32, jnp.int32
+    bh = SLOTS * KV_HEADS                  # MHA: one query head per kv head
+    pool = (KV_HEADS, POOL_PAGES, PAGE, HD)
+    da = "decode_attention"
+    return {
+        "paged_decode_call": (da, "paged_decode_call", {"group": 1}, [
+            ((bh, 1, HD), bf), (pool, bf), (pool, bf),
+            ((SLOTS, TABLE_PAGES), i32),
+            ((SLOTS, TABLE_PAGES * PAGE), f32)]),
+        "paged_verify_call": (da, "paged_verify_call", {"group": 1}, [
+            ((bh, CHUNK, HD), bf), (pool, bf), (pool, bf),
+            ((SLOTS, TABLE_PAGES), i32),
+            ((SLOTS, CHUNK, TABLE_PAGES * PAGE), f32)]),
+        "decode_call": (da, "decode_call", {"group": 1,
+                                            "block_k": DRAFT_WIDTH}, [
+            ((bh, 1, HD), bf), ((bh, DRAFT_WIDTH, HD), bf),
+            ((bh, DRAFT_WIDTH, HD), bf), ((SLOTS, DRAFT_WIDTH), f32)]),
+        "flash_call": ("flash_attention", "flash_call", {
+            "causal": True, "block_q": 128, "block_k": 128,
+            "valid_len": 204}, [
+            ((KV_HEADS, 256, HD), bf), ((KV_HEADS, 256, HD), bf),
+            ((KV_HEADS, 256, HD), bf)]),
+    }
+
+
+@pytest.mark.parametrize("kernel", ["paged_decode_call", "paged_verify_call",
+                                    "decode_call", "flash_call"])
+def test_kernel_compiles_for_v5e_at_lisa7b_widths(one_chip, kernel):
+    package, fn_name, kwargs, shapes = _cases()[kernel]
+    fn = functools.partial(getattr(_kernels(package), fn_name),
+                           interpret=False, **kwargs)
+    compiled = _compile(one_chip, fn, *shapes)
+    assert "tpu_custom_call" in compiled.as_text()
