@@ -11,9 +11,11 @@ driver (``repro.analysis.lint``):
   * ``RepoModel`` — the whole lint target. Its one non-trivial product
     is the **traced set**: the transitive closure of functions that
     execute under ``jax.jit`` tracing. Seeds are jit decorators, direct
-    ``jax.jit(fn)`` / ``jax.jit(lambda ...)`` wraps, and the
-    stage-factory idiom (``jax.jit(self._stage_fn(...))`` marks the
-    factory's returned closures); the closure propagates through
+    ``jax.jit(fn)`` / ``jax.jit(lambda ...)`` wraps, the stage-factory
+    idiom (``jax.jit(self._stage_fn(...))`` marks the factory's
+    returned closures), and the function handed to a stage-naming
+    wrapper (``named_stage(name, fn)``, which exists only to be
+    jitted); the closure propagates through
     resolvable call edges — same-module calls, ``self.method`` calls,
     and cross-module ``alias.fn`` calls through the import map. The
     host-sync checker asks "is this ``.item()`` inside traced code?"
@@ -40,6 +42,9 @@ PALLAS_CALL_NAMES = {"pallas_call"}
 # memoisation decorators: a jit built under one of these is built once
 # per distinct key, not per call
 CACHE_DECORATORS = {"lru_cache", "cache", "cached_property"}
+# wrappers that name a function for the jit that takes it
+# (``core.spans.named_stage(name, fn)``): ``fn`` is the traced body
+NAME_WRAPPERS = {"named_stage"}
 
 
 @dataclass(frozen=True)
@@ -289,6 +294,15 @@ def is_pallas_callee(func: ast.AST, mod: ModuleInfo) -> bool:
     return False
 
 
+def named_body(node: ast.AST) -> ast.AST:
+    """The function a stage-naming wrapper call wraps
+    (``named_stage(name, fn)`` -> ``fn``); any other node as it is."""
+    while (isinstance(node, ast.Call) and len(node.args) == 2
+           and decorator_name(node.func) in NAME_WRAPPERS):
+        node = node.args[1]
+    return node
+
+
 def has_jit_decorator(node: FuncNode, mod: ModuleInfo) -> bool:
     for dec in getattr(node, "decorator_list", []):
         if is_jit_callee(dec, mod):               # @jax.jit / @jit
@@ -350,9 +364,13 @@ class RepoModel:
             if has_jit_decorator(fn.node, mod):
                 seeds.add((mod.rel, qual))
         for node in ast.walk(mod.tree):
-            if not (isinstance(node, ast.Call)
-                    and (is_jit_callee(node.func, mod)
-                         or is_pallas_callee(node.func, mod))):
+            if not isinstance(node, ast.Call):
+                continue
+            if named_body(node) is not node:
+                seeds |= self._resolve_jit_arg(mod, node)
+                continue
+            if not (is_jit_callee(node.func, mod)
+                    or is_pallas_callee(node.func, mod)):
                 continue
             if not node.args:
                 continue
@@ -362,6 +380,7 @@ class RepoModel:
     def _resolve_jit_arg(self, mod: ModuleInfo, arg: ast.AST
                          ) -> Set[FuncKey]:
         """Functions put under tracing by ``jax.jit(<arg>)``."""
+        arg = named_body(arg)
         if isinstance(arg, ast.Lambda):
             key = self._lambda_key(mod, arg)
             return {key} if key else set()

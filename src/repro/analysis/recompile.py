@@ -28,7 +28,7 @@ from typing import List, Optional, Set, Tuple
 
 from repro.analysis.model import (Finding, FunctionInfo, ModuleInfo,
                                   RepoModel, is_jit_callee,
-                                  is_pallas_callee)
+                                  is_pallas_callee, named_body)
 
 CHECKER = "recompile"
 
@@ -220,7 +220,7 @@ def _check_captured_scalars(mod: ModuleInfo, fn: FunctionInfo,
     """AV102: the jitted closure captures a per-call-varying local."""
     if not call.args:
         return
-    arg = call.args[0]
+    arg = named_body(call.args[0])
     target: Optional[ast.AST] = None
     if isinstance(arg, ast.Lambda):
         target = arg

@@ -42,6 +42,7 @@ from repro.core import bottleneck as bn
 from repro.core import packets as pk
 from repro.core import vlm
 from repro.core.lut import SystemLUT, Tier
+from repro.core.spans import named_stage
 
 
 class Stream(enum.Enum):
@@ -89,13 +90,16 @@ class DualStreamExecutor:
         # hot loop; prefill keeps the full-sequence path
         self._gen_pcfg = dataclasses.replace(
             pcfg, llm=pcfg.llm.replace(use_flash_decode=self.flash_decode))
-        self._edge_context = jax.jit(
-            lambda p, img: vlm.clip_encode(p, pcfg, img))
-        self._edge_insight = jax.jit(
-            lambda p, img: vlm.sam_head(p, pcfg, img))
+        # every stage is jitted under its own name (core.spans), so its
+        # XLA module is jit_<stage> in a profiler trace
+        self._edge_context = jax.jit(named_stage(
+            "edge_context", lambda p, img: vlm.clip_encode(p, pcfg, img)))
+        self._edge_insight = jax.jit(named_stage(
+            "edge_insight", lambda p, img: vlm.sam_head(p, pcfg, img)))
         # one shared jitted bottleneck encode for every tier (tiers differ
         # only in code rank, which the jit cache keys on via shape)
-        self._encode = jax.jit(lambda bp, a: bn.encode(bp, a))
+        self._encode = jax.jit(named_stage(
+            "bottleneck_encode", lambda bp, a: bn.encode(bp, a)))
         # explicit compile cache: (stage, tier, bucket, query_len) ->
         # jitted callable.
         # Each entry owns exactly one compiled executable (bucket shapes
@@ -105,20 +109,23 @@ class DualStreamExecutor:
         # paged decode step over all live slots with per-row positions and
         # page tables, the prefix-page scatter into the shared pool, and
         # the standalone mask decode
-        self._decode_paged = jax.jit(
+        self._decode_paged = jax.jit(named_stage(
+            "cloud_decode_rows",
             lambda p, pool, pt, posarr, tok, pos, ws:
             vlm.llm_decode_step_paged(p, self._gen_pcfg, pool, pt, posarr,
-                                      tok, pos, ws))
+                                      tok, pos, ws)))
         # speculative verify: one paged multi-token pass over every live
         # slot's chunk (last accepted token + drafts); the jit cache keys
         # on the chunk width C via the tokens shape
-        self._verify_paged = jax.jit(
+        self._verify_paged = jax.jit(named_stage(
+            "cloud_verify_rows",
             lambda p, pool, pt, posarr, tok, pos, ws, cl:
             vlm.llm_verify_step_paged(p, self._gen_pcfg, pool, pt, posarr,
-                                      tok, pos, ws, cl))
-        self._mask_decode = jax.jit(
-            lambda p, feats, seg: vlm.mask_decode(p, pcfg, feats, seg))
-        self._pool_write = jax.jit(_pool_write)
+                                      tok, pos, ws, cl)))
+        self._mask_decode = jax.jit(named_stage(
+            "cloud_mask",
+            lambda p, feats, seg: vlm.mask_decode(p, pcfg, feats, seg)))
+        self._pool_write = jax.jit(named_stage("pool_write", _pool_write))
 
     # ---- compile cache ----
 
@@ -173,7 +180,8 @@ class DualStreamExecutor:
                self.flash_decode, self.page_size, width)
         fn = self._compiled.get(key)
         if fn is None:
-            fn = jax.jit(self._stage_fn(stage, width=width))
+            fn = jax.jit(named_stage(stage,
+                                     self._stage_fn(stage, width=width)))
             self._compiled[key] = fn
         return fn
 

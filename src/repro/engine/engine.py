@@ -41,8 +41,9 @@ from repro.core.intent import (DEFAULT_REQUIREMENTS, Intent,
                                IntentRequirements, classify_intent)
 from repro.core.lut import SystemLUT
 from repro.core.paging import PagePool
+from repro.core.spans import span
 from repro.engine.api import Request, RequestFuture, Response
-from repro.engine.inflight import InflightDecoder
+from repro.engine.inflight import DecodeTotals, InflightDecoder
 from repro.engine.observability import (FlightRecorder, MetricsRegistry,
                                         Tracer)
 from repro.engine.policy import (AdaptivePolicy, ControlPolicy, RetryPolicy,
@@ -256,6 +257,7 @@ class AveryEngine:
         self._retired_inflight = (0, 0)   # (steps, slot-steps) of evicted
         self._retired_faults = (0, 0)     # (cancels, stage faults) of evicted
         self._retired_spec = SpecStats()  # spec telemetry of evicted
+        self._retired_totals = DecodeTotals()  # device work of evicted
         self._futures: Dict[int, RequestFuture] = {}
         self._order: List[int] = []
         self._seq = 0
@@ -399,6 +401,15 @@ class AveryEngine:
             spec.merge(d.spec_stats)
         return spec
 
+    def _merged_totals(self) -> DecodeTotals:
+        """Engine-lifetime decoder totals: retired decoders' plus every
+        live decoder's."""
+        totals = DecodeTotals()
+        totals.merge(self._retired_totals)
+        for d in self._inflight.values():
+            totals.merge(d.totals)
+        return totals
+
     def _spec_gate(self, stats: SpecStats) -> bool:
         """The policy's drafting gate. Decided on the *engine-lifetime*
         acceptance stats, not the calling decoder's own (``stats``) —
@@ -479,20 +490,23 @@ class AveryEngine:
             raise RuntimeError(        # must not leave a ghost request
                 "this engine has no executor; real submissions need one "
                 "(profiled missions go through session.submit_frame)")
-        intent = request.intent
-        if intent is None:
-            intent = request.intent = session.classify(request.prompt)
-        session.history.append((request.time_s, request.prompt, intent))
-        fut = self._register(request, session)
-        fut.meta["session"] = session
-        fut.meta["deadline"] = self._deadline_for(session, intent,
-                                                  request.time_s)
-        self._advance(request.time_s)
-        if self._reject_overload(fut, session, request.time_s):
+        with span("engine.submit") as sp:
+            intent = request.intent
+            if intent is None:
+                intent = request.intent = session.classify(request.prompt)
+            session.history.append((request.time_s, request.prompt,
+                                    intent))
+            fut = self._register(request, session)
+            sp.set_metadata(rid=request.request_id)
+            fut.meta["session"] = session
+            fut.meta["deadline"] = self._deadline_for(session, intent,
+                                                      request.time_s)
+            self._advance(request.time_s)
+            if self._reject_overload(fut, session, request.time_s):
+                return fut
+            self._attempt(fut, request.time_s)
+            self._sweep_deadlines()
             return fut
-        self._attempt(fut, request.time_s)
-        self._sweep_deadlines()
-        return fut
 
     def _reject_overload(self, fut: RequestFuture,
                          session: OperatorSession, t: float) -> bool:
@@ -718,24 +732,26 @@ class AveryEngine:
                 "(profiled missions go through session.submit_frame)")
         session = session or (self.sessions[0] if self.sessions
                               else self.session("_direct"))
-        fut = self._register(Request(intent=intent, query=np.asarray(query),
-                                     time_s=time_s,
-                                     priority=session.priority
-                                     if priority is None
-                                     else int(priority)), session)
-        decision = TierDecision(
-            stream=packet.kind,
-            tier=self.lut.by_name(packet.tier_name) if packet.tier_name
-            else None)
-        fut.meta.update(session=session, fixed_packet=packet,
-                        decision=decision,
-                        deadline=self._deadline_for(session, intent, time_s))
-        self._advance(time_s)
-        if self._reject_overload(fut, session, time_s):
+        with span("engine.submit") as sp:
+            fut = self._register(Request(
+                intent=intent, query=np.asarray(query), time_s=time_s,
+                priority=session.priority if priority is None
+                else int(priority)), session)
+            sp.set_metadata(rid=fut.request.request_id)
+            decision = TierDecision(
+                stream=packet.kind,
+                tier=self.lut.by_name(packet.tier_name) if packet.tier_name
+                else None)
+            fut.meta.update(session=session, fixed_packet=packet,
+                            decision=decision,
+                            deadline=self._deadline_for(session, intent,
+                                                        time_s))
+            self._advance(time_s)
+            if self._reject_overload(fut, session, time_s):
+                return fut
+            self._attempt_packet(fut, time_s)
+            self._sweep_deadlines()
             return fut
-        self._attempt_packet(fut, time_s)
-        self._sweep_deadlines()
-        return fut
 
     # ---- cloud dispatch: closed microbatches or the in-flight batch ----
 
@@ -893,20 +909,21 @@ class AveryEngine:
         microbatches, or one in-flight decode step per live decoder.
         Sweeps deadlines first — an overdue request must not consume a
         decode step it can no longer use."""
-        self._sweep_deadlines()
-        if self._scheduler is not None:
-            for res in self._scheduler.step_ready():
-                self._resolve_scheduled(res)
-        with self._transfer_guard():
-            for dec in self._inflight.values():
-                dec.pump(1)
-        if self.tracer.enabled:
-            load = self.scheduler_proto.load()
-            for key in sorted(load):
-                self.metrics.gauge(key).set(load[key])
-        if self.debug_invariants:
-            self._audit_pool()
-        self.check_sanitizers()
+        with span("engine.pump"):
+            self._sweep_deadlines()
+            if self._scheduler is not None:
+                for res in self._scheduler.step_ready():
+                    self._resolve_scheduled(res)
+            with self._transfer_guard():
+                for dec in self._inflight.values():
+                    dec.pump(1)
+            if self.tracer.enabled:
+                load = self.scheduler_proto.load()
+                for key in sorted(load):
+                    self.metrics.gauge(key).set(load[key])
+            if self.debug_invariants:
+                self._audit_pool()
+            self.check_sanitizers()
 
     def drain(self, release_operator: Optional[str] = None
               ) -> List[Response]:
@@ -936,6 +953,7 @@ class AveryEngine:
             self._retired_faults = (cancels + dec.n_cancelled,
                                     faults + dec.n_stage_faults)
             self._retired_spec.merge(dec.spec_stats)
+            self._retired_totals.merge(dec.totals)
             del self._inflight[qlen]
         out, remaining = [], []
         for rid in self._order:
@@ -1230,6 +1248,7 @@ class AveryEngine:
                 d.n_cancelled for d in self._inflight.values())
             out["stage_faults"] = faults + sum(
                 d.n_stage_faults for d in self._inflight.values())
+            out.update(self._merged_totals().as_dict())
             out.update(self.kv_pool.stats())
             if self.spec_config is not None:
                 out.update(self._merged_spec_stats().as_dict())

@@ -58,7 +58,7 @@ accepted only where it equals the serving model's own greedy pick.
 from __future__ import annotations
 
 from collections import deque
-from dataclasses import dataclass
+from dataclasses import dataclass, fields
 from typing import Any, Callable, Deque, Dict, List, Optional, Tuple
 
 import numpy as np
@@ -67,11 +67,35 @@ from repro.core import packets as pk
 from repro.core.intent import Intent
 from repro.core.paging import (TRASH_PAGE, PagePool, pages_for,
                                prefix_digest, prefix_positions)
+from repro.core.spans import span
 from repro.engine.faults import CloudStageError
 from repro.engine.observability import Tracer
 from repro.engine.scheduler import FifoScheduler, qos_class
 from repro.engine.speculative import (DraftModel, SpecStats,
                                       SpeculativeConfig, greedy_accept)
+
+
+@dataclass
+class DecodeTotals:
+    """Running totals of the device work one decoder launched (the
+    engine folds retired decoders' totals into its own, like
+    ``SpecStats``). Each is one integer, bumped where the work is
+    launched, so the totals stay bounded however long a mission runs."""
+    attended_positions: int = 0  # per fed token: the pos + 1 it attends
+    admissions: int = 0      # requests admitted into a slot
+    prefix_misses: int = 0   # admissions that ran the prefix prefill
+    prefill_tokens: int = 0  # prefix tokens those prefills ran
+    sam_tails: int = 0       # SAM tails run (Insight admissions)
+    masks: int = 0           # masks decoded
+
+    def merge(self, other: "DecodeTotals") -> None:
+        for f in fields(self):
+            setattr(self, f.name,
+                    getattr(self, f.name) + getattr(other, f.name))
+
+    def as_dict(self) -> Dict[str, int]:
+        return {f"inflight_{f.name}": getattr(self, f.name)
+                for f in fields(self)}
 
 
 @dataclass
@@ -179,6 +203,7 @@ class InflightDecoder:
         self.step_idx = 0                 # global decode-step counter
         self.n_steps = 0
         self.n_slot_steps = 0             # sum of live slots across steps
+        self.totals = DecodeTotals()
         self.n_served = 0
         self.n_cancelled = 0              # requests removed via cancel()
         self.n_stage_faults = 0           # CloudStageErrors absorbed
@@ -326,7 +351,10 @@ class InflightDecoder:
                 "failure": "deadline"})
             return 0
         try:
-            slot, st = self._admit_one(item)
+            # the span ends with the request's first token on the host
+            with span("inflight.admit", rid=item.seq_id) as sp:
+                slot, st = self._admit_one(item)
+                sp.set_metadata(hit=int(st.prefix_hit))
             self.scheduler.note_admitted(item, now)
             st.t_admit = now
             if item.t_first_token is None:
@@ -364,6 +392,8 @@ class InflightDecoder:
         hit = entry is not None
         if not hit:
             logits0, paged = self.executor.cloud_prefix(ctx, item.query)
+            self.totals.prefix_misses += 1
+            self.totals.prefill_tokens += self.prefix_len
             self.pool.ensure(
                 self.n_prefix_pages, like=paged,
                 capacity_hint=1 + self.slots * (self.n_prefix_pages
@@ -390,6 +420,8 @@ class InflightDecoder:
         except Exception:
             self.pool.release(entry.page_ids)
             raise
+        if feats is not None:
+            self.totals.sam_tails += 1
         speculative = (self.spec is not None
                        and item.speculative is not False)
         # speculating rows allocate decode pages lazily per verify
@@ -417,9 +449,10 @@ class InflightDecoder:
             # same key as the target prefix store: repeat-prefix
             # frames skip the draft prefill too (honouring the
             # pool's sharing knob so baselines stay baselines)
-            self.draft.admit(slot, ctx, item.query,
-                             key=key if self.pool.share_prefixes
-                             else None)
+            with span("inflight.draft"):
+                self.draft.admit(slot, ctx, item.query,
+                                 key=key if self.pool.share_prefixes
+                                 else None)
         st = _SlotState(
             req=item, tokens=[int(np.argmax(entry.logits0[0]))],
             logits0=entry.logits0, feats=feats, pos=self.prefix_len,
@@ -438,6 +471,7 @@ class InflightDecoder:
             # request stays token-exact with an uninterrupted one.
             st.replay = deque(item.resume_tokens[1:])
         self.active[slot] = st
+        self.totals.admissions += 1
         return slot, st
 
     # ---- cancellation (deadline enforcement) ----
@@ -486,22 +520,23 @@ class InflightDecoder:
         batch."""
         if not self.active:
             return 0
-        draft_rows = {}
-        if self.spec is not None and self.draft is not None:
-            # resumed rows replay their parked tokens through the plain
-            # path first (drafting against a replay is pointless — the
-            # outcome is already known); they rejoin drafting once the
-            # replay drains
-            candidates = {s: st for s, st in self.active.items()
-                          if st.speculative and len(st.tokens) < self.T
-                          and not st.replay}
-            if candidates and self.spec_gate(self.spec_stats):
-                draft_rows = candidates
-            elif candidates:
-                self.spec_stats.disabled_steps += 1
-        if draft_rows:
-            return self._step_verify(draft_rows)
-        return self._step_plain()
+        with span("inflight.step"):
+            draft_rows = {}
+            if self.spec is not None and self.draft is not None:
+                # resumed rows replay their parked tokens through the
+                # plain path first (drafting against a replay is
+                # pointless — the outcome is already known); they rejoin
+                # drafting once the replay drains
+                candidates = {s: st for s, st in self.active.items()
+                              if st.speculative and len(st.tokens) < self.T
+                              and not st.replay}
+                if candidates and self.spec_gate(self.spec_stats):
+                    draft_rows = candidates
+                elif candidates:
+                    self.spec_stats.disabled_steps += 1
+            if draft_rows:
+                return self._step_verify(draft_rows)
+            return self._step_plain()
 
     def _step_plain(self) -> int:
         """One single-token decode step over all live rows (the non-
@@ -509,62 +544,69 @@ class InflightDecoder:
         the policy has disabled, and rows that only need their final
         <SEG> read)."""
         base = self.n_prefix_pages * self.pool.page_size
-        toks = np.zeros((self.slots, 1), np.int32)
-        # free rows decode garbage through the trash page (their page
-        # tables were reset on release); outputs are discarded
-        pos = np.zeros((self.slots,), np.int32)
-        write_slot = np.zeros((self.slots,), np.int32)
-        for s, st in self.active.items():
-            # speculating rows manage decode pages lazily — make sure the
-            # slot being written is covered (no-op for plain rows, whose
-            # pages were allocated up front)
-            self._grow_private(s, st, len(st.tokens))
-            toks[s, 0] = st.tokens[-1]
-            pos[s] = st.pos
-            write_slot[s] = base + len(st.tokens) - 1
+        with span("inflight.step.inputs"):
+            toks = np.zeros((self.slots, 1), np.int32)
+            # free rows decode garbage through the trash page (their page
+            # tables were reset on release); outputs are discarded
+            pos = np.zeros((self.slots,), np.int32)
+            write_slot = np.zeros((self.slots,), np.int32)
+            for s, st in self.active.items():
+                # speculating rows manage decode pages lazily — make sure
+                # the slot being written is covered (no-op for plain
+                # rows, whose pages were allocated up front)
+                self._grow_private(s, st, len(st.tokens))
+                toks[s, 0] = st.tokens[-1]
+                pos[s] = st.pos
+                write_slot[s] = base + len(st.tokens) - 1
         wc = self._wallclock
         w0 = wc() if wc is not None else 0.0
         try:
-            logits, seg, self.pool.kv = self.executor.cloud_decode_rows(
-                self.pool.kv, self.page_tables, self.positions, toks, pos,
-                write_slot)
+            with span("inflight.step.launch"):
+                logits, seg, self.pool.kv = self.executor.cloud_decode_rows(
+                    self.pool.kv, self.page_tables, self.positions, toks,
+                    pos, write_slot)
         except CloudStageError as e:
             return self._fail_step(e)
+        with span("inflight.step.fetch"):
+            logits, seg = np.asarray(logits), np.asarray(seg)
+        # the step as the host sees it: launch, device and the copy back
         if wc is not None and self._metrics is not None:
             self._metrics.histogram("decode_step_s").observe(wc() - w0)
-        logits, seg = np.asarray(logits), np.asarray(seg)
         live = len(self.active)
         self.n_steps += 1
         self.n_slot_steps += live
         now = self._clock()
         finished = 0
-        for s, st in list(self.active.items()):
-            n = len(st.tokens)
-            self.positions[s, base + n - 1] = st.pos
-            st.steps_done += 1
-            st.batch_acc += live
-            if self._cost is not None:
+        with span("inflight.step.sample"):
+            for s, st in list(self.active.items()):
+                n = len(st.tokens)
+                self.positions[s, base + n - 1] = st.pos
+                st.steps_done += 1
+                st.batch_acc += live
                 # one fed token attending st.pos + 1 cached positions
-                st.flops += self._cost.token_flops(st.pos + 1)
-                st.hbm_bytes += self._cost.token_hbm_bytes(st.pos + 1)
-            if self.tracer.enabled:
-                self.tracer.point(st.req.seq_id, "decode_step", now,
-                                  slot=s, step=self.step_idx)
-            if n < self.T:
-                if st.replay:
-                    # replaying a parked run: the stored token IS the
-                    # greedy pick (deterministic decode), so feeding it
-                    # keeps the resumed row token-exact
-                    st.tokens.append(st.replay.popleft())
-                    self.scheduler.note_replayed()
-                else:
-                    st.tokens.append(int(np.argmax(logits[s])))
-                st.pos += 1
-                continue
-            # final step: this row's seg is the <SEG> state at the last
-            # generated token (llm_generate's convention for every T)
-            st.seg = seg[s]
-            finished += self._finish_slot(s, st)
+                self.totals.attended_positions += st.pos + 1
+                if self._cost is not None:
+                    st.flops += self._cost.token_flops(st.pos + 1)
+                    st.hbm_bytes += self._cost.token_hbm_bytes(st.pos + 1)
+                if self.tracer.enabled:
+                    self.tracer.point(st.req.seq_id, "decode_step", now,
+                                      slot=s, step=self.step_idx)
+                if n < self.T:
+                    if st.replay:
+                        # replaying a parked run: the stored token IS the
+                        # greedy pick (deterministic decode), so feeding
+                        # it keeps the resumed row token-exact
+                        st.tokens.append(st.replay.popleft())
+                        self.scheduler.note_replayed()
+                    else:
+                        st.tokens.append(int(np.argmax(logits[s])))
+                    st.pos += 1
+                    continue
+                # final step: this row's seg is the <SEG> state at the
+                # last generated token (llm_generate's convention for
+                # every T)
+                st.seg = seg[s]
+                finished += self._finish_slot(s, st)
         self.step_idx += 1
         if finished:
             self.admit()              # freed slots let queued requests in
@@ -580,97 +622,112 @@ class InflightDecoder:
         C = k + 1
         page = self.pool.page_size
         base = self.n_prefix_pages * page
-        proposals = self.draft.draft(
-            {s: st.tokens for s, st in draft_rows.items()}, k,
-            budgets={s: self.T - len(st.tokens)
-                     for s, st in draft_rows.items()})
-        toks = np.zeros((self.slots, C), np.int32)
-        pos = np.zeros((self.slots,), np.int32)
-        write_slot = np.zeros((self.slots,), np.int32)
-        clens = np.ones((self.slots,), np.int32)
-        n_drafted: Dict[int, int] = {}
-        for s, st in self.active.items():
-            n = len(st.tokens)
-            toks[s, 0] = st.tokens[-1]
-            pos[s] = st.pos
-            write_slot[s] = base + n - 1
-            if s in proposals:
-                j = min(k, self.T - n)        # never draft past the answer
-                n_drafted[s] = j
-                toks[s, 1:1 + j] = proposals[s][:j]
-                clens[s] = 1 + j
-            # cover the chunk (incl. the draft overhang) with decode pages
-            self._grow_private(s, st, n - 1 + int(clens[s]))
+        with span("inflight.draft"):
+            proposals = self.draft.draft(
+                {s: st.tokens for s, st in draft_rows.items()}, k,
+                budgets={s: self.T - len(st.tokens)
+                         for s, st in draft_rows.items()})
+        with span("inflight.step.inputs"):
+            toks = np.zeros((self.slots, C), np.int32)
+            pos = np.zeros((self.slots,), np.int32)
+            write_slot = np.zeros((self.slots,), np.int32)
+            clens = np.ones((self.slots,), np.int32)
+            n_drafted: Dict[int, int] = {}
+            for s, st in self.active.items():
+                n = len(st.tokens)
+                toks[s, 0] = st.tokens[-1]
+                pos[s] = st.pos
+                write_slot[s] = base + n - 1
+                if s in proposals:
+                    j = min(k, self.T - n)    # never draft past the answer
+                    n_drafted[s] = j
+                    toks[s, 1:1 + j] = proposals[s][:j]
+                    clens[s] = 1 + j
+                # cover the chunk (incl. the draft overhang) with decode
+                # pages
+                self._grow_private(s, st, n - 1 + int(clens[s]))
         wc = self._wallclock
         w0 = wc() if wc is not None else 0.0
         try:
-            logits, seg, self.pool.kv = self.executor.cloud_verify_rows(
-                self.pool.kv, self.page_tables, self.positions, toks, pos,
-                write_slot, clens)
+            with span("inflight.step.launch"):
+                logits, seg, self.pool.kv = self.executor.cloud_verify_rows(
+                    self.pool.kv, self.page_tables, self.positions, toks,
+                    pos, write_slot, clens)
         except CloudStageError as e:
             return self._fail_step(e)
+        with span("inflight.step.fetch"):
+            logits, seg = np.asarray(logits), np.asarray(seg)
         if wc is not None and self._metrics is not None:
             self._metrics.histogram("verify_step_s").observe(wc() - w0)
-        logits, seg = np.asarray(logits), np.asarray(seg)
         live = len(self.active)
         self.n_steps += 1
         self.n_slot_steps += live
         now = self._clock()
         finished = 0
-        for s, st in list(self.active.items()):
-            n = len(st.tokens)
-            j = n_drafted.get(s, 0)
-            if self._cost is not None:
-                # every fed chunk token costs device compute whether or
-                # not its draft is accepted — rejected drafts are real
-                # FLOPs, which is exactly what the ledger should show
-                for i in range(int(clens[s])):
-                    st.flops += self._cost.token_flops(st.pos + i + 1)
-                    st.hbm_bytes += self._cost.token_hbm_bytes(
-                        st.pos + i + 1)
-            # greedy[i]: the serving model's own pick after chunk token i
-            greedy = np.argmax(logits[s, :1 + j], axis=-1)
-            m = greedy_accept(toks[s, 1:1 + j], greedy) if j else 0
-            # chunk tokens 0..m are now committed: the real last token
-            # plus m accepted drafts
-            for i in range(m + 1):
-                self.positions[s, base + n - 1 + i] = st.pos + i
-            new = [int(g) for g in greedy[:m + 1]][:self.T - n]
-            st.tokens.extend(new)
-            st.pos += len(new)
-            if st.replay:
-                # a resumed row riding someone else's verify batch
-                # advances by the model's own greedy picks — identical
-                # to the parked tokens — so its replay drains in step
-                for _ in new:
-                    if st.replay:
-                        st.replay.popleft()
-                        self.scheduler.note_replayed()
-            st.steps_done += 1
-            st.batch_acc += live
-            if self.tracer.enabled:
-                self.tracer.point(st.req.seq_id, "verify_step", now,
-                                  slot=s, step=self.step_idx,
-                                  drafted=j, accepted=int(m))
-            if j:
-                # accepted drafts the draft model itself fed (d_1..d_{j-1}
-                # — the j-th came off the last feed's logits) already live
-                # in its cache at their committed positions: skip their
-                # catch-up feed next round
-                self.draft.commit(s, n + min(m, j - 1))
-                self.spec_stats.note_chunk(j, m, len(new),
-                                           metrics=self._metrics)
-                # rollback: free decode pages past the accepted length
-                dropped = self.pool.rollback_to(st.private_ids, n + m)
-                if dropped:
-                    self.spec_stats.pages_rolled_back += len(dropped)
-                    lo = self.n_prefix_pages + len(st.private_ids)
-                    self.page_tables[s, lo:lo + len(dropped)] = TRASH_PAGE
-            if n - 1 + m >= self.T - 1:
-                # the answer's final token was fed and accepted in this
-                # chunk: its hidden state is the <SEG> read
-                st.seg = seg[s, self.T - n]
-                finished += self._finish_slot(s, st)
+        with span("inflight.step.sample"):
+            for s, st in list(self.active.items()):
+                n = len(st.tokens)
+                j = n_drafted.get(s, 0)
+                # chunk token i attends st.pos + i + 1 positions
+                c = int(clens[s])
+                self.totals.attended_positions += \
+                    c * (st.pos + 1) + c * (c - 1) // 2
+                if self._cost is not None:
+                    # every fed chunk token costs device compute whether
+                    # or not its draft is accepted — rejected drafts are
+                    # real FLOPs, which is exactly what the ledger should
+                    # show
+                    for i in range(c):
+                        st.flops += self._cost.token_flops(st.pos + i + 1)
+                        st.hbm_bytes += self._cost.token_hbm_bytes(
+                            st.pos + i + 1)
+                # greedy[i]: the serving model's own pick after chunk
+                # token i
+                greedy = np.argmax(logits[s, :1 + j], axis=-1)
+                m = greedy_accept(toks[s, 1:1 + j], greedy) if j else 0
+                # chunk tokens 0..m are now committed: the real last
+                # token plus m accepted drafts
+                for i in range(m + 1):
+                    self.positions[s, base + n - 1 + i] = st.pos + i
+                new = [int(g) for g in greedy[:m + 1]][:self.T - n]
+                st.tokens.extend(new)
+                st.pos += len(new)
+                if st.replay:
+                    # a resumed row riding someone else's verify batch
+                    # advances by the model's own greedy picks —
+                    # identical to the parked tokens — so its replay
+                    # drains in step
+                    for _ in new:
+                        if st.replay:
+                            st.replay.popleft()
+                            self.scheduler.note_replayed()
+                st.steps_done += 1
+                st.batch_acc += live
+                if self.tracer.enabled:
+                    self.tracer.point(st.req.seq_id, "verify_step", now,
+                                      slot=s, step=self.step_idx,
+                                      drafted=j, accepted=int(m))
+                if j:
+                    # accepted drafts the draft model itself fed
+                    # (d_1..d_{j-1} — the j-th came off the last feed's
+                    # logits) already live in its cache at their
+                    # committed positions: skip their catch-up feed next
+                    # round
+                    self.draft.commit(s, n + min(m, j - 1))
+                    self.spec_stats.note_chunk(j, m, len(new),
+                                               metrics=self._metrics)
+                    # rollback: free decode pages past the accepted length
+                    dropped = self.pool.rollback_to(st.private_ids, n + m)
+                    if dropped:
+                        self.spec_stats.pages_rolled_back += len(dropped)
+                        lo = self.n_prefix_pages + len(st.private_ids)
+                        self.page_tables[s, lo:lo + len(dropped)] = \
+                            TRASH_PAGE
+                if n - 1 + m >= self.T - 1:
+                    # the answer's final token was fed and accepted in
+                    # this chunk: its hidden state is the <SEG> read
+                    st.seg = seg[s, self.T - n]
+                    finished += self._finish_slot(s, st)
         self.step_idx += 1
         if finished:
             self.admit()
@@ -707,49 +764,51 @@ class InflightDecoder:
         """Deliver a finished row: decode its mask from the stored SAM
         feats and the captured <SEG> state, hand the result back, and
         release its pages."""
-        if self.tracer.enabled:
-            # close this residency segment: preemption round-trips give
-            # one decode span per segment, bounded by park/resume points
-            now = self._clock()
-            self.tracer.span(st.req.seq_id, "decode", st.t_admit,
-                             max(now, st.t_admit), slot=s,
-                             tokens=len(st.tokens))
-        mask = None
-        if st.feats is not None:
-            try:
-                mask = np.asarray(self.executor.cloud_mask(
-                    st.feats, st.seg[None]))
-            except CloudStageError as e:
-                self.n_stage_faults += 1
-                self._release_slot(s, st)
-                st.req.on_done({
-                    "seq_id": st.req.seq_id, "intent": st.req.intent,
-                    "tier_name": st.req.packet.tier_name,
-                    "failure": "cloud_error", "error": str(e)})
-                return 1
-        st.req.on_done({
-            "seq_id": st.req.seq_id,
-            "intent": st.req.intent,
-            "tier_name": st.req.packet.tier_name,
-            "answer_logits": st.logits0,
-            "mask_logits": mask,
-            "tokens": np.asarray(st.tokens, np.int32)[None, :],
-            "batch_size": st.batch_acc / max(1, st.steps_done),
-            "joined_step": st.joined_step,
-            "prefix_hit": st.prefix_hit,
-            "speculative": st.speculative,
-            "preemptions": st.req.resumes,
-            "queue_wait": st.req.queue_wait,
-            "t_first_token": st.req.t_first_token,
-            "cloud_flops": st.flops if self._cost is not None else None,
-            "cloud_hbm_bytes": st.hbm_bytes
-            if self._cost is not None else None,
-        })
-        if st.req.resumes:
-            self.scheduler.note_resumed_served()
-        self._release_slot(s, st)
-        self.n_served += 1
-        return 1
+        with span("inflight.finish", rid=st.req.seq_id):
+            if self.tracer.enabled:
+                # close this residency segment: preemption round-trips give
+                # one decode span per segment, bounded by park/resume points
+                now = self._clock()
+                self.tracer.span(st.req.seq_id, "decode", st.t_admit,
+                                 max(now, st.t_admit), slot=s,
+                                 tokens=len(st.tokens))
+            mask = None
+            if st.feats is not None:
+                try:
+                    mask = np.asarray(self.executor.cloud_mask(
+                        st.feats, st.seg[None]))
+                except CloudStageError as e:
+                    self.n_stage_faults += 1
+                    self._release_slot(s, st)
+                    st.req.on_done({
+                        "seq_id": st.req.seq_id, "intent": st.req.intent,
+                        "tier_name": st.req.packet.tier_name,
+                        "failure": "cloud_error", "error": str(e)})
+                    return 1
+                self.totals.masks += 1
+            st.req.on_done({
+                "seq_id": st.req.seq_id,
+                "intent": st.req.intent,
+                "tier_name": st.req.packet.tier_name,
+                "answer_logits": st.logits0,
+                "mask_logits": mask,
+                "tokens": np.asarray(st.tokens, np.int32)[None, :],
+                "batch_size": st.batch_acc / max(1, st.steps_done),
+                "joined_step": st.joined_step,
+                "prefix_hit": st.prefix_hit,
+                "speculative": st.speculative,
+                "preemptions": st.req.resumes,
+                "queue_wait": st.req.queue_wait,
+                "t_first_token": st.req.t_first_token,
+                "cloud_flops": st.flops if self._cost is not None else None,
+                "cloud_hbm_bytes": st.hbm_bytes
+                if self._cost is not None else None,
+            })
+            if st.req.resumes:
+                self.scheduler.note_resumed_served()
+            self._release_slot(s, st)
+            self.n_served += 1
+            return 1
 
     def _release_slot(self, slot: int, st: _SlotState) -> None:
         """Return the slot's pages (prefix ref + private pages) and park

@@ -40,6 +40,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import vlm
+from repro.core.spans import named_stage
 
 
 @functools.lru_cache(maxsize=None)
@@ -50,11 +51,13 @@ def _draft_fns(pcfg, width: int):
     recompile the (unchanged) draft stages on every burst. Configs are
     frozen dataclasses, so they key the cache directly; params ride in
     as arguments and never retrigger compilation."""
-    prefill = jax.jit(
-        lambda p, c, q: vlm.llm_prefill(p, pcfg, c, q, width=width))
-    step = jax.jit(
-        lambda p, ca, t, pos: vlm.llm_decode_step(p, pcfg, ca, t, pos))
-    insert = jax.jit(DraftModel._insert_row)
+    prefill = jax.jit(named_stage(
+        "draft_prefill",
+        lambda p, c, q: vlm.llm_prefill(p, pcfg, c, q, width=width)))
+    step = jax.jit(named_stage(
+        "draft_step",
+        lambda p, ca, t, pos: vlm.llm_decode_step(p, pcfg, ca, t, pos)))
+    insert = jax.jit(named_stage("draft_insert", DraftModel._insert_row))
     return prefill, step, insert
 
 

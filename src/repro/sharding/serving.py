@@ -60,6 +60,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import vlm
+from repro.core.spans import named_stage
 from repro.sharding import specs as sh
 
 
@@ -122,17 +123,21 @@ class ShardedServingContext:
         """One jitted stage per key; in/out shardings are computed from
         the first call's arguments/abstract outputs (the sharding trees
         are shape-polymorphic, so later shapes re-trace under the same
-        jit without re-deriving them)."""
+        jit without re-deriving them). The stage is jitted under its
+        key's name (the first entry of a tuple key)."""
         stage = self._stages.get(key)
         if stage is None:
             box: Dict[str, Callable] = {}
+            named = named_stage(key[0] if isinstance(key, tuple) else key,
+                                fn)
 
             def call(*args):
                 jitted = box.get("jitted")
                 if jitted is None:
                     outs = jax.eval_shape(fn, *args)
                     jitted = box["jitted"] = jax.jit(
-                        fn, in_shardings=in_sh(args), out_shardings=out_sh(outs))
+                        named, in_shardings=in_sh(args),
+                        out_shardings=out_sh(outs))
                 return jitted(*args)
 
             stage = self._stages[key] = call

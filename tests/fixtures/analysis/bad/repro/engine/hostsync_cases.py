@@ -34,3 +34,19 @@ def helper(x):
 @jax.jit
 def calls_helper(x):
     return helper(x)
+
+
+def named_stage(name, fn):
+    return fn
+
+
+def named_helper(x):
+    return int(x.max())                  # AV202 via a named stage
+
+
+class NamedStages:
+    def __init__(self):
+        # a stage-naming wrapper leaves its body traced
+        self._lam = jax.jit(named_stage(
+            "lam", lambda x: x * x.item()))  # AV202 in the named lambda
+        self._fn = jax.jit(named_stage("helper", named_helper))

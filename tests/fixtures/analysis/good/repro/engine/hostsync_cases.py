@@ -23,3 +23,14 @@ def host_side(x):
     if float(arr[0]) > 0:
         return int(arr.sum())
     return arr.item()
+
+
+def named_stage(name, fn):
+    return fn
+
+
+class NamedStages:
+    def __init__(self):
+        # the named body is traced, and reads only static shapes
+        self._fn = jax.jit(named_stage(
+            "reshape", lambda x: x.reshape(int(x.shape[0]), -1)))

@@ -59,6 +59,17 @@ def test_hostsync_flags_traced_callee():
     assert len(hits) == 1 and hits[0].code == "AV202"
 
 
+@pytest.mark.parametrize("symbol", [
+    "NamedStages.__init__.<lambda@", "named_helper"])
+def test_hostsync_sees_through_stage_naming(symbol):
+    """``jax.jit(named_stage(name, fn))`` traces ``fn`` as
+    ``jax.jit(fn)`` would: a lambda body and a module function handed
+    to the naming wrapper are both in the traced closure."""
+    hits = [f for f in _findings(BAD, "hostsync")
+            if f.symbol.startswith(symbol)]
+    assert len(hits) == 1 and hits[0].code == "AV202"
+
+
 def test_refcount_flags_both_acquisitions():
     msgs = {f.message.split("(")[0] for f in _findings(BAD, "refcount")}
     assert any("pool.alloc" in m for m in msgs)
