@@ -52,19 +52,19 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     page_table (B, n_pages) i32 page ids (all entries must be valid —
     point unused rows at the reserved trash page); bias
     (B, n_pages*page) additive over the gathered virtual sequence.
-    Returns (B,H,hd). One kv block per page, page table resolved via
-    scalar prefetch.
+    Returns (B,H,hd). One program per row and block of whole pages; the
+    pool is read in its stored layout: XLA tiles the pool's (K, hd) rows
+    contiguously (in (2, 128) tiles at K 2, (8, 128) at K 8 and 32), so
+    merging its page and head dims is a bitcast, and nothing is
+    transposed. The page table is resolved via scalar prefetch.
     """
     B, H, hd = q.shape
-    K = k_pool.shape[2]
-    G = H // K
-    qh = q.reshape(B, K, G, hd).reshape(B * H, 1, hd)
-    kh = k_pool.transpose(2, 0, 1, 3)                  # (K, P, page, hd)
-    vh = v_pool.transpose(2, 0, 1, 3)
-    out = _k.paged_decode_call(qh, kh, vh,
-                               jnp.asarray(page_table, jnp.int32), bias,
-                               group=G)
-    return out.reshape(B, K, G, hd).reshape(B, H, hd)
+    P, page, K, _ = k_pool.shape
+    out = _k.paged_decode_call(q.reshape(B, K, H // K, hd),
+                               k_pool.reshape(P, page * K, hd),
+                               v_pool.reshape(P, page * K, hd),
+                               jnp.asarray(page_table, jnp.int32), bias)
+    return out.reshape(B, H, hd)
 
 
 @jax.jit

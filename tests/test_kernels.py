@@ -1,5 +1,7 @@
 """Per-kernel validation: sweep shapes/dtypes, assert_allclose against the
 pure-jnp ref.py oracles (assignment deliverable c)."""
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -160,10 +162,36 @@ def test_decode_attention_bf16():
                                rtol=5e-2, atol=5e-2)
 
 
-@pytest.mark.parametrize("B,H,K,hd,P,page,n",
-                         [(2, 4, 2, 64, 9, 16, 3), (1, 8, 8, 32, 5, 8, 4),
-                          (3, 4, 1, 128, 12, 32, 2)])
-def test_paged_decode_attention_matches_ref(B, H, K, hd, P, page, n):
+def _serving_rows(rng, B, P, page, n):
+    """Page table and bias laid out as the in-flight engine lays out its
+    rows: a prefix shared by every row whose last page is part-filled (a
+    masked hole mid-row), private answer pages, and unused trailing pages
+    parked on the trash page 0."""
+    n_prefix = n // 2
+    ids = rng.permutation(np.arange(1, P))
+    prefix, private = ids[:n_prefix], ids[n_prefix:]
+    table = np.zeros((B, n), np.int32)
+    bias = np.full((B, n * page), -1e30, np.float32)
+    for b in range(B):
+        table[b, :n_prefix] = prefix
+        n_answer = 1 + b % (n - n_prefix - 1)          # at least one trash page
+        table[b, n_prefix:n_prefix + n_answer] = rng.choice(private, n_answer)
+        bias[b, :n_prefix * page - page // 2 - b] = 0  # the prefix, then a hole
+        answer = n_prefix * page
+        bias[b, answer:answer + (n_answer - 1) * page + 1 + b] = 0
+    return jnp.asarray(table), jnp.asarray(bias)
+
+
+@pytest.mark.parametrize("B,H,K,hd,P,page,n,rows", [
+    (2, 4, 2, 64, 9, 16, 3, "ragged"), (1, 8, 8, 32, 5, 8, 4, "ragged"),
+    (3, 4, 1, 128, 12, 32, 2, "ragged"),
+    # G = 1, 3, 6 at hd 128, rows as the engine serves them
+    (3, 8, 8, 128, 20, 16, 6, "serving"), (3, 24, 8, 128, 20, 16, 6, "serving"),
+    (3, 12, 2, 128, 20, 16, 6, "serving"),
+    # more pages than one block holds, and not a multiple of the block
+    (2, 32, 32, 128, 12, 16, 11, "serving"),
+    (2, 24, 8, 128, 30, 16, 23, "serving")])
+def test_paged_decode_attention_matches_ref(B, H, K, hd, P, page, n, rows):
     """Page-table gather path == dense oracle over the gathered layout."""
     from repro.kernels.decode_attention import ops as dops
     from repro.kernels.decode_attention import ref as dref
@@ -171,16 +199,61 @@ def test_paged_decode_attention_matches_ref(B, H, K, hd, P, page, n):
     q = jnp.asarray(rng.randn(B, H, hd), jnp.float32)
     kp = jnp.asarray(rng.randn(P, page, K, hd), jnp.float32)
     vp = jnp.asarray(rng.randn(P, page, K, hd), jnp.float32)
-    pt = jnp.asarray(rng.randint(0, P, (B, n)), jnp.int32)
-    # ragged validity: tail of each row's virtual sequence masked, as the
-    # paged serving cache does for empty slots
-    bias = np.zeros((B, n * page), np.float32)
-    for i, L in enumerate(np.linspace(page, n * page, B).astype(int)):
-        bias[i, L:] = -1e30
-    out = dops.paged_decode_attention(q, kp, vp, pt, jnp.asarray(bias))
-    ref = dref.paged_decode_attention_ref(q, kp, vp, pt, jnp.asarray(bias))
+    if rows == "serving":
+        pt, bias = _serving_rows(rng, B, P, page, n)
+    else:
+        pt = jnp.asarray(rng.randint(0, P, (B, n)), jnp.int32)
+        # ragged validity: tail of each row's virtual sequence masked, as
+        # the paged serving cache does for empty slots
+        bias = np.zeros((B, n * page), np.float32)
+        for i, L in enumerate(np.linspace(page, n * page, B).astype(int)):
+            bias[i, L:] = -1e30
+        bias = jnp.asarray(bias)
+    out = dops.paged_decode_attention(q, kp, vp, pt, bias)
+    ref = dref.paged_decode_attention_ref(q, kp, vp, pt, bias)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-4, atol=2e-5)
+
+
+@pytest.mark.parametrize("n_pages,K,itemsize,expect", [
+    (16, 8, 2, 16),     # Phi-4-mini, a Context row: the whole row at once
+    (14, 32, 2, 7),     # LISA-7B widths: two blocks of seven pages
+    (80, 8, 2, 27),     # a long answer: three blocks, the last one short
+    (11, 32, 4, 4),     # the f32 multi-block test case: 3 blocks, 1 padded
+    (23, 8, 4, 12)])    # two blocks, one padded
+def test_paged_decode_pages_per_block(n_pages, K, itemsize, expect):
+    """The block of pages comes from the operands' shapes: as many pages
+    as the VMEM budget holds, spread evenly over the fewest blocks."""
+    import importlib
+    kmod = importlib.import_module(
+        "repro.kernels.decode_attention.decode_attention")
+    ppb = kmod.pages_per_block(n_pages, 16 * K * 128, itemsize)
+    assert ppb == expect
+    per_page = 2 * 16 * K * 128 * (2 * itemsize + 4)
+    assert ppb * per_page <= kmod._PAGED_VMEM_BYTES
+
+
+def test_paged_decode_attention_lowers_without_pool_transpose(monkeypatch):
+    """Lowered for a TPU from this host, the jitted wrapper reads the
+    pool through a reshape alone (no transpose anywhere), and the Pallas
+    call carries the name the benchmark's trace reader times."""
+    import importlib
+    from repro.kernels.decode_attention import ops as dops
+    kmod = importlib.import_module(
+        "repro.kernels.decode_attention.decode_attention")
+    monkeypatch.setattr(kmod, "resolve_interpret",
+                        lambda interpret=None: False)
+    S = jax.ShapeDtypeStruct
+    pool = S((255, 16, 8, 128), jnp.bfloat16)
+    args = (S((8, 24, 128), jnp.bfloat16), pool, pool,
+            S((8, 16), jnp.int32), S((8, 256), jnp.float32))
+    # a fresh jit, so that no interpreted trace is reused or left behind
+    fn = jax.jit(dops.paged_decode_attention.__wrapped__)
+    text = fn.trace(*args).lower(lowering_platforms=("tpu",)).as_text()
+    assert "transpose" not in text
+    assert "tensor<255x16x8x128xbf16>) -> tensor<255x128x128xbf16>" in text
+    names = re.findall(r'kernel_name = "([^"]*)"', text)
+    assert len(names) == 1 and "paged_decode" in names[0]
 
 
 @pytest.mark.parametrize("B,C,H,K,hd,P,page,n",

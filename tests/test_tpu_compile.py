@@ -1,6 +1,7 @@
 """The serving path's Pallas kernels compile for a TPU v5e at lisa-7b
 widths (hd 128, 32 kv heads, page 16, bf16 pool, up to 16 slots, a
-4-token verify chunk).
+4-token verify chunk), and the paged decode kernel at Phi-4-mini widths
+too (24 query heads over 8 kv heads, 8 slots, 16 or 80 pages a row).
 
 The chip is described, not attached: the TPU compiler lowers each kernel
 with ``interpret=False`` for one device of a ``v5e:2x2`` topology, which
@@ -54,11 +55,13 @@ def _cases():
     bf, f32, i32 = jnp.bfloat16, jnp.float32, jnp.int32
     bh = SLOTS * KV_HEADS                  # MHA: one query head per kv head
     pool = (KV_HEADS, POOL_PAGES, PAGE, HD)
+    # the paged decode kernel reads the stored pool, page and head merged
+    rows_pool = (POOL_PAGES, PAGE * KV_HEADS, HD)
     da = "decode_attention"
     return {
-        "paged_decode_call": (da, "paged_decode_call", {"group": 1}, [
-            ((bh, 1, HD), bf), (pool, bf), (pool, bf),
-            ((SLOTS, TABLE_PAGES), i32),
+        "paged_decode_call": (da, "paged_decode_call", {}, [
+            ((SLOTS, KV_HEADS, 1, HD), bf), (rows_pool, bf),
+            (rows_pool, bf), ((SLOTS, TABLE_PAGES), i32),
             ((SLOTS, TABLE_PAGES * PAGE), f32)]),
         "paged_verify_call": (da, "paged_verify_call", {"group": 1}, [
             ((bh, CHUNK, HD), bf), (pool, bf), (pool, bf),
@@ -83,4 +86,24 @@ def test_kernel_compiles_for_v5e_at_lisa7b_widths(one_chip, kernel):
     fn = functools.partial(getattr(_kernels(package), fn_name),
                            interpret=False, **kwargs)
     compiled = _compile(one_chip, fn, *shapes)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("pages,pool_pages", [(16, 255), (80, 641)])
+def test_paged_decode_compiles_for_v5e_at_phi4mini_widths(one_chip, pages,
+                                                          pool_pages):
+    """8 slots, 24 query heads over 8 kv heads, a bf16 pool. 16 pages a
+    row (14 of prefix, 2 of answer) in a 255-page pool: the whole row is
+    one block of pages, double-buffered in VMEM. 80 pages a row, a long
+    answer: three blocks of 27, the largest the VMEM budget gives, with
+    their float32 copies and per-head temporaries."""
+    bf, f32, i32 = jnp.bfloat16, jnp.float32, jnp.int32
+    slots, heads, kv_heads = 8, 24, 8
+    pool = ((pool_pages, PAGE * kv_heads, HD), bf)
+    fn = functools.partial(_kernels("decode_attention").paged_decode_call,
+                           interpret=False)
+    compiled = _compile(one_chip, fn,
+                        ((slots, kv_heads, heads // kv_heads, HD), bf),
+                        pool, pool, ((slots, pages), i32),
+                        ((slots, pages * PAGE), f32))
     assert "tpu_custom_call" in compiled.as_text()
