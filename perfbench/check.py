@@ -16,9 +16,11 @@ the engine served. Numbers compared, each against its limit in
   decode). Pooled over frames: one 64x64 mask's error swings threefold
   from seed to seed.
 
-The control is the reference in fp8 (``reference`` ``mode="fp8"``) in the
-program's place: its gap is read at the token it puts first at each
-position of the same prompts and tokens.
+The trunk's reference is the configuration's architecture module's
+(``arch/<arch>.py``); the SAM tail and mask are ``reference.py``'s. The
+control is the reference in fp8 (``mode="fp8"``) in the program's
+place: its gap is read at the token it puts first at each position of
+the same prompts and tokens.
 """
 from __future__ import annotations
 
@@ -28,7 +30,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from perfbench import reference as ref
+from perfbench import arch, reference as ref
 
 NUMBERS = ("token_gap", "first_logits_err", "mask_err")
 
@@ -68,10 +70,10 @@ def numbers(params, bottlenecks, cfg, frames, picked, control: bool = False
                                             else "clip"] for r in picked])
     query = np.concatenate([r.query for r in picked])
     tokens = np.concatenate([r.tokens for r in picked])
-    logits, seg = ref.trunk(params, cfg, ctx, query, tokens)
+    trunk = arch.resolve(cfg).trunk
+    logits, seg = trunk(params, cfg, ctx, query, tokens)
     if control:
-        c_logits, c_seg = ref.trunk(params, cfg, ctx, query, tokens,
-                                    mode="fp8")
+        c_logits, c_seg = trunk(params, cfg, ctx, query, tokens, mode="fp8")
         chosen = jnp.argmax(c_logits, axis=-1)
         first = np.asarray(c_logits[:, 0])
     else:

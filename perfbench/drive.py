@@ -127,9 +127,22 @@ class Record:
     failure: Optional[str] = None
 
 
-def build_engine(pcfg, params, bottlenecks, mix, uavs, traced: bool):
+def model_mesh(devs):
+    """The program's serving mesh (``launch.mesh.make_local_mesh``) with
+    every one of ``devs`` on its "model" axis."""
+    from repro.launch.mesh import make_local_mesh
+    mesh = make_local_mesh(model=len(devs))
+    if set(mesh.devices.flat) != set(devs):
+        raise SystemExit(f"a {len(devs)}-chip cell needs a host of "
+                         f"{len(devs)} chips; the mesh spans {mesh.size}")
+    return mesh
+
+
+def build_engine(pcfg, params, bottlenecks, mix, uavs, traced: bool,
+                 mesh=None):
     """The executor with the compiled kernels, behind the proxy, in an
-    in-flight engine with one session per UAV.
+    in-flight engine with one session per UAV; with ``mesh``, the
+    engine's sharded serving (``AveryEngine(mesh=...)``).
 
     Options the program cannot choose itself are set here, from shapes:
     the page pool is sized for every slot's prefix and answer pages plus
@@ -143,14 +156,18 @@ def build_engine(pcfg, params, bottlenecks, mix, uavs, traced: bool):
     lut = paper_lut()
     executor = DualStreamExecutor(pcfg, params, bottlenecks, lut,
                                   max_new_tokens=int(mix["answer_len"]))
-    proxy = StageProxy(executor, traced=traced)
     page = executor.page_size
     slots = AveryEngine.__init__.__kwdefaults__["max_batch"]
     n_prefix = -(-(pcfg.clip_tokens + int(mix["query_len"])) // page)
     n_answer = -(-int(mix["answer_len"]) // page)
     kv_pages = 1 + (2 * slots + 1) * n_prefix + slots * n_answer
-    engine = AveryEngine(lut=lut, executor=proxy, batching="inflight",
-                         kv_pages=kv_pages, max_prefixes=slots)
+    engine = AveryEngine(lut=lut, executor=executor, batching="inflight",
+                         kv_pages=kv_pages, max_prefixes=slots, mesh=mesh)
+    # the proxy goes between the engine and what it serves through (with
+    # a mesh, the sharded context the engine put around the executor);
+    # the engine makes its decoders from ``executor`` when first asked
+    proxy = StageProxy(engine.executor, traced=traced)
+    engine.executor = proxy
     default_tier = lut.tiers[0].name
     sessions = [engine.session(u.name,
                                policy=StaticTierPolicy(u.tier or default_tier))

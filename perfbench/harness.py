@@ -2,9 +2,12 @@
 check, and the result.
 
 Everything a cell needs is found by name: the cell in ``BENCHMARK.json``,
-its configuration in the file that entry names, its traffic in
+its configuration in the file that entry names, the configuration's trunk
+architecture in ``arch/<arch>.py``, its traffic in
 ``traffic/<traffic>.json``, its limits in ``limits/<cell>.json`` and each
-per-layer metric's reader in ``metrics/<metric>.py``.
+per-layer metric's reader in ``metrics/<metric>.py``. A cell's ``chips``
+is honoured: on more than one, the weights are made sharded and the
+engine serves through a mesh of those chips.
 """
 from __future__ import annotations
 
@@ -111,7 +114,8 @@ def run(spec: Dict[str, Any], seed: int, seconds: float, traced: bool,
     it); ``control`` also reads the fp8 control's numbers on the same
     sample."""
     import jax
-    from perfbench import check, drive, model, peaks as peaks_mod, readers
+    from perfbench import arch, check, drive, model, peaks as peaks_mod
+    from perfbench import readers
     from perfbench import trace as trace_mod, traffic as tr
     from repro.launch.cache import use_compile_cache
 
@@ -124,14 +128,15 @@ def run(spec: Dict[str, Any], seed: int, seconds: float, traced: bool,
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
 
     pcfg = model.pipeline_config(cfg)
+    mesh = drive.model_mesh(devs) if len(devs) > 1 else None
     uavs = tr.fleet(mix)
     tiers = sorted({u.tier for u in uavs if u.tier})
     params, bottlenecks = model.make_weights(pcfg, cfg["bottleneck_tiers"],
-                                             seed)
+                                             seed, mesh)
     frames = model.make_frames(pcfg, bottlenecks, tiers,
                                int(mix["frame_pool"]), seed)
     engine, proxy, sessions = drive.build_engine(pcfg, params, bottlenecks,
-                                                 mix, uavs, traced)
+                                                 mix, uavs, traced, mesh)
     if wrap is not None:
         proxy.__class__ = wrap
     source = tr.RequestSource(mix, pcfg.llm.vocab_size,
@@ -167,6 +172,7 @@ def run(spec: Dict[str, Any], seed: int, seconds: float, traced: bool,
         state["steps"] = stats["inflight_steps"] - state["steps0"]
         state["slot_steps"] = (stats["inflight_steps"]
                                * stats["mean_live_slots"] - state["slots0"])
+        state["model_shards"] = stats.get("model_shards", 1)
         if traced:
             state["span"].__exit__(None, None, None)
             jax.profiler.stop_trace()
@@ -217,17 +223,18 @@ def run(spec: Dict[str, Any], seed: int, seconds: float, traced: bool,
                 for k, v in by_intent.items()},
             "backlog_at_close": len(backlog),
             "last_answer_after_close_s": max(0.0, last - t_close),
-            "decode_steps": state["steps"]}
+            "decode_steps": state["steps"],
+            "model_shards": state["model_shards"]}
 
     if traced:
-        trace = trace_mod.load(trace_dir)
+        trace = trace_mod.load(trace_dir, [d.id for d in devs])
         span = [h for h in trace["host"] if h[2] == "window"]
         lo, hi = span[0][0], span[0][1]
         red = trace_mod.reduce(trace, lo, hi, readers.KERNELS)
         shutil.rmtree(trace_dir, ignore_errors=True)
         ctx = {"trace": red, "counts": proxy.counts, "steps": state["steps"],
                "slot_steps": state["slot_steps"], "pcfg": pcfg,
-               "peaks": peaks}
+               "arch": arch.resolve(cfg), "chips": len(devs), "peaks": peaks}
         for m in spec["per_layer"]:
             v = reader(m["name"])(ctx)
             if v is not None:

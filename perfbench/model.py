@@ -4,8 +4,9 @@ benchmark makes for it from the seed.
 ``pipeline_config`` turns a configuration file (``configs/<name>.json``)
 into the program's ``LISAPipelineConfig``. ``make_weights`` makes every
 weight the program serves, in the program's layout and types, on the
-device in one jitted call from the seed: the program's own init is asked
-only for the layout (``jax.eval_shape``), never for values. ``make_frames``
+device (or, sharded, on the devices of a mesh) in one jitted call from
+the seed: the program's own init is asked only for the layout
+(``jax.eval_shape``), never for values. ``make_frames``
 makes the pool of edge payloads a request can carry: CLIP context
 features for the trunk's prefix and, per Insight tier, int8 bottleneck
 codes with their per-token scales, as a UAV would send them.
@@ -32,20 +33,13 @@ def _encoder_cfg(name: str, enc: Dict[str, Any], dtype: str):
 
 
 def pipeline_config(cfg: Dict[str, Any]):
-    """The program's pipeline config for one configuration file."""
+    """The program's pipeline config for one configuration file: the
+    trunk as its architecture module maps it (``arch.resolve``), SAM and
+    CLIP as every configuration has them."""
+    from perfbench import arch
     from repro.configs.lisa7b import LISAPipelineConfig
-    from repro.models import ModelConfig
-    t, dtype = cfg["trunk"], cfg["dtype"]
-    if t["hidden_act"] != "silu":
-        raise ValueError(f"trunk activation {t['hidden_act']} not served")
-    llm = ModelConfig(
-        name=cfg["name"], arch_type="dense",
-        num_layers=t["num_hidden_layers"], d_model=t["hidden_size"],
-        num_heads=t["num_attention_heads"],
-        num_kv_heads=t["num_key_value_heads"], d_ff=t["intermediate_size"],
-        vocab_size=t["vocab_size"], head_dim=t["head_dim"],
-        qkv_bias=bool(t["attention_bias"]), rope_theta=t["rope_theta"],
-        norm_eps=t["rms_norm_eps"], param_dtype=dtype, act_dtype=dtype)
+    dtype = cfg["dtype"]
+    llm = arch.resolve(cfg).llm_config(cfg)
     sam, clip = cfg["sam"], cfg["clip"]
     return LISAPipelineConfig(
         name=cfg["name"], sam=_encoder_cfg("sam", sam, dtype),
@@ -102,9 +96,20 @@ def weight_layout(pcfg, tiers: Sequence[str]):
     return params, {t: bns[t] for t in tiers}
 
 
-def make_weights(pcfg, tiers: Sequence[str], seed: int):
+def weight_shardings(pcfg, layout, mesh):
+    """Where each weight lives on ``mesh``: the program's key-path rules
+    (``sharding.specs.param_specs``), the ones its sharded serving
+    context places the weights by."""
+    from repro.sharding import specs
+    return specs.to_shardings(mesh, specs.param_specs(pcfg.llm, layout,
+                                                      mesh))
+
+
+def make_weights(pcfg, tiers: Sequence[str], seed: int, mesh=None):
     """(params, bottlenecks by tier) from ``seed``, made on the device in
-    one jitted call."""
+    one jitted call. With ``mesh`` each weight is made where the program
+    keeps it, so no chip ever holds the whole trunk; the values do not
+    depend on the mesh."""
     layout = weight_layout(pcfg, tiers)
     leaves, treedef = jax.tree.flatten(layout)
     paths = _paths(layout)
@@ -115,7 +120,9 @@ def make_weights(pcfg, tiers: Sequence[str], seed: int):
             _leaf_value(k, p, l.shape, l.dtype)
             for k, p, l in zip(keys, paths, leaves)])
 
-    out = jax.jit(init)(jax.random.fold_in(seed_key(seed), 1))
+    jitted = jax.jit(init) if mesh is None else jax.jit(
+        init, out_shardings=weight_shardings(pcfg, layout, mesh))
+    out = jitted(jax.random.fold_in(seed_key(seed), 1))
     return jax.block_until_ready(out)
 
 

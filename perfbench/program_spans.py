@@ -216,8 +216,8 @@ def main(argv=None) -> int:
     seen: Dict[str, object] = {}
     base_load = trace_mod.load
 
-    def load_both(log_dir: str) -> Dict[str, object]:
-        seen["trace"] = base_load(log_dir)
+    def load_both(log_dir: str, ids=None) -> Dict[str, object]:
+        seen["trace"] = base_load(log_dir, ids)
         seen["prog"] = load(log_dir)
         return seen["trace"]
 

@@ -5,6 +5,7 @@ import jax.numpy as jnp
 import pytest
 
 from perfbench import flops, model
+from perfbench.arch import dense_gqa
 from perfbench.tests import tiny
 
 
@@ -16,24 +17,25 @@ def pcfg():
 def test_trunk_token_is_twice_the_weights_plus_attention(pcfg):
     # 2 layers: q/o 64x64 each, k/v 64x32 each, 3 MLP 64x128
     weights = 2 * (2 * 64 * 64 + 2 * 64 * 32 + 3 * 64 * 128)
-    assert flops.trunk_token_flops(pcfg, 10) == \
+    assert dense_gqa.token_flops(pcfg, 10) == \
         2 * weights + 2 * (2 * 2 * 10 * 4 * 16)
 
 
 def test_prefill_sums_a_causal_triangle(pcfg):
     n = 7
-    want = sum(flops.trunk_token_flops(pcfg, i) for i in range(1, n + 1))
-    assert flops.prefill_flops(pcfg, n) == want + flops.head_flops(pcfg)
+    want = sum(dense_gqa.token_flops(pcfg, i) for i in range(1, n + 1))
+    assert flops.prefill_flops(dense_gqa, pcfg, n) == \
+        want + flops.head_flops(pcfg)
     assert flops.head_flops(pcfg) == 2 * 64 * 256 + 2 * 64 * 32
 
 
 def test_paged_attention_counts_live_rows_once(pcfg):
-    f, b = flops.paged_attention_cost(pcfg, [10, 20])
+    f, b = dense_gqa.paged_attention_cost(pcfg, [10, 20])
     assert f == 2 * (2 * 2 * 30 * 4 * 16)
     assert b == 2 * ((2 * 30 * 2 * 16 + 2 * 2 * 4 * 16) * 2)
-    assert flops.paged_attention_cost(pcfg, []) == (0.0, 0.0)
-    assert flops.decode_step_flops(pcfg, [10, 20]) == (
-        flops.trunk_token_flops(pcfg, 10) + flops.trunk_token_flops(pcfg, 20)
+    assert dense_gqa.paged_attention_cost(pcfg, []) == (0.0, 0.0)
+    assert flops.decode_step_flops(dense_gqa, pcfg, [10, 20]) == (
+        dense_gqa.token_flops(pcfg, 10) + dense_gqa.token_flops(pcfg, 20)
         + 2 * flops.head_flops(pcfg))
 
 
@@ -58,3 +60,31 @@ def test_sam_tail_matches_xla_count_of_the_same_matmuls(pcfg):
     xla = cost["flops"] if isinstance(cost, dict) else cost[0]["flops"]
     mine = flops.sam_tail_flops(pcfg, rank)
     assert abs(xla - mine) / mine < 0.02
+
+
+@pytest.mark.parametrize("metric", ["mfu", "paged_decode_roofline.throughput"])
+def test_a_four_chip_cell_reads_a_quarter_of_one_chips_share(pcfg, metric):
+    """The same window's work over four chips' peaks: the readers divide
+    whole-model counts by the cell's chips, and at one chip read what
+    one chip's peaks give."""
+    from perfbench import harness, peaks, readers
+    from perfbench.arch import dense_gqa
+    ctx = {"trace": {"window_s": 2.0, "busy_s": 1.5,
+                     "kernel_s": {"paged_decode": 0.004}},
+           "counts": {"decode_ctx": [[213, 240, 99], [214, 241]],
+                      "prefill_len": [212, 212], "sam_rank": [5],
+                      "mask": 1},
+           "steps": 2, "slot_steps": 5, "pcfg": pcfg, "arch": dense_gqa,
+           "peaks": peaks.peaks("TPU v5 lite")}
+    read = harness.reader(metric)
+    one, four = read(dict(ctx, chips=1)), read(dict(ctx, chips=4))
+    assert one > 0 and four == pytest.approx(one / 4, rel=1e-12)
+    p = ctx["peaks"]
+    if metric == "mfu":
+        assert one == 100.0 * readers.model_flops(ctx) / (
+            2.0 * p["bf16_flops"])
+    else:
+        least = sum(max(f / p["bf16_flops"], b / p["hbm_bytes_per_s"])
+                    for f, b in (dense_gqa.paged_attention_cost(pcfg, c)
+                                 for c in ctx["counts"]["decode_ctx"]))
+        assert one == 100.0 * least / 0.004

@@ -67,3 +67,22 @@ def test_short_names():
     assert trace.short("%fusion.64 = bf16[8,200064]{1,0:T(8,128)} "
                        "fusion(bf16[3072,200064]{1,0} %p)") == \
         "%fusion.64 = bf16[8,200064]"
+
+
+def test_a_one_chip_cell_on_a_four_chip_host_reads_its_own_plane():
+    """Four TPU planes, the other three idle or busy with other work: a
+    cell on chip 0 reads plane 0's busy time, not the average."""
+    mine = _trace()["devices"][0]
+    planes = {"/device:TPU:0": mine,
+              "/device:TPU:1": [],
+              "/device:TPU:2": [(0, 1000, "%fusion.3 = f32[8]")],
+              "/device:TPU:3": []}
+    own = {"devices": trace.own_planes(planes, [0]),
+           "host": _trace()["host"]}
+    red = trace.reduce(own, 0, 1000, {"paged_decode": "paged_decode"})
+    assert red["busy_s"] == pytest.approx(400e-9)
+    assert red["kernel_s"]["paged_decode"] == pytest.approx(190e-9)
+    assert len(trace.own_planes(planes, [0, 1, 2, 3])) == 4
+    assert trace.own_planes(planes, None) == list(planes.values())
+    with pytest.raises(ValueError, match="4"):
+        trace.own_planes(planes, [4])

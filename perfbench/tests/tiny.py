@@ -32,15 +32,17 @@ OPEN = {"loop": "open", "query_len": 4, "answer_len": 4, "gap_shape": 1.8,
         "frame_pool": 4, "sample": {"context": 2, "insight": 2}}
 
 
-def spec(mix, limits, per_layer=()):
-    return {"cell": {"name": "tiny", "chips": 1},
-            "config": copy.deepcopy(CONFIG), "traffic": copy.deepcopy(mix),
+def spec(mix, limits, per_layer=(), chips=1, config=CONFIG):
+    return {"cell": {"name": "tiny", "chips": chips},
+            "config": copy.deepcopy(config), "traffic": copy.deepcopy(mix),
             "limits": dict(limits),
             "end_to_end": [{"name": "setup_s", "unit": "s"}],
             "per_layer": list(per_layer)}
 
 
-def run(mix, limits, seed=2**31 + 77, seconds=2.0, **kw):
+def run(mix, limits, seed=2**31 + 77, seconds=2.0, chips=1, config=CONFIG,
+        **kw):
     from perfbench import harness
-    return harness.run(spec(mix, limits), seed, seconds, False,
-                       time.perf_counter(), on_chip=False, **kw)
+    return harness.run(spec(mix, limits, chips=chips, config=config), seed,
+                       seconds, False, time.perf_counter(), on_chip=False,
+                       **kw)

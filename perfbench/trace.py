@@ -1,7 +1,8 @@
 """Reduction of a profiler trace to the numbers the per-layer readers use.
 
 ``load`` reads the ``.xplane.pb`` a traced run wrote, with nothing but
-JAX, into plain lists: the device operations of each TPU plane and the
+JAX, into plain lists: the device operations of each TPU plane of the
+cell's chips (``/device:TPU:<id>``, by JAX's device ids) and the
 benchmark's own host spans (``drive.py``: ``pump``, ``submit``,
 ``generator_wait`` and one per executor stage), all in nanoseconds on one
 clock. ``reduce`` takes those lists and the window's bounds and returns
@@ -14,7 +15,7 @@ from __future__ import annotations
 import bisect
 import glob
 import os
-from typing import Dict, Iterable, List, Sequence, Tuple
+from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
 Interval = Tuple[float, float, str]          # (start_ns, end_ns, name)
 
@@ -22,29 +23,46 @@ HOST_SPANS = ("window", "pump", "submit", "generator_wait", "cloud_prefix",
               "pool_write", "cloud_decode_rows", "cloud_sam_feats",
               "cloud_mask")
 DEVICE_LINE = "XLA Ops"
+DEVICE_PLANE = "/device:TPU:"
 
 
-def load(log_dir: str) -> Dict[str, object]:
-    """Device operations per TPU plane and the benchmark's host spans."""
+def load(log_dir: str, ids: Optional[Sequence[int]] = None
+         ) -> Dict[str, object]:
+    """Device operations of the TPU planes of chips ``ids`` (every TPU
+    plane where None) and the benchmark's host spans."""
     from jax.profiler import ProfileData
     paths = glob.glob(os.path.join(log_dir, "plugins", "profile", "*",
                                    "*.xplane.pb"))
     if not paths:
         raise FileNotFoundError(f"no trace under {log_dir}")
     data = ProfileData.from_file(max(paths, key=os.path.getmtime))
-    devices: List[List[Interval]] = []
+    planes: Dict[str, List[Interval]] = {}
     host: List[Interval] = []
     for plane in data.planes:
-        if plane.name.startswith("/device:TPU:"):
-            ops = [(e.start_ns, e.start_ns + e.duration_ns, e.name)
-                   for line in plane.lines if line.name == DEVICE_LINE
-                   for e in line.events]
-            devices.append(ops)
+        if plane.name.startswith(DEVICE_PLANE):
+            planes[plane.name] = [
+                (e.start_ns, e.start_ns + e.duration_ns, e.name)
+                for line in plane.lines if line.name == DEVICE_LINE
+                for e in line.events]
         elif plane.name.startswith("/host:"):
             host += [(e.start_ns, e.start_ns + e.duration_ns, e.name)
                      for line in plane.lines for e in line.events
                      if e.name in HOST_SPANS]
-    return {"devices": devices, "host": host}
+    return {"devices": own_planes(planes, ids), "host": host}
+
+
+def own_planes(planes: Dict[str, List[Interval]],
+               ids: Optional[Sequence[int]]) -> List[List[Interval]]:
+    """The operations of the planes of chips ``ids``, in that order (of
+    every plane, in the trace's order, where None): a cell that runs on
+    some of a host's chips reads none of the others'."""
+    if ids is None:
+        return list(planes.values())
+    missing = [i for i in ids if f"{DEVICE_PLANE}{i}" not in planes]
+    if missing:
+        raise ValueError(f"the trace holds no plane of chips {missing}; "
+                         f"it holds {sorted(planes)}")
+    return [planes[f"{DEVICE_PLANE}{i}"] for i in ids]
 
 
 def union(intervals: Iterable[Interval], lo: float, hi: float
