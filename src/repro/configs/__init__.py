@@ -67,6 +67,8 @@ def get_reduced(name: str) -> ModelConfig:
     if cfg.moe is not None:
         kw["moe"] = dataclasses.replace(
             cfg.moe, num_experts=4, top_k=2, d_ff_expert=64,
+            n_group=min(cfg.moe.n_group, 2),
+            topk_group=min(cfg.moe.topk_group, 1),
             d_ff_shared=64 if cfg.moe.num_shared_experts else 0,
             first_k_dense=min(1, cfg.moe.first_k_dense),
             d_ff_dense=256 if cfg.moe.first_k_dense else 0)

@@ -126,6 +126,14 @@ class DualStreamExecutor:
             "cloud_mask",
             lambda p, feats, seg: vlm.mask_decode(p, pcfg, feats, seg)))
         self._pool_write = jax.jit(named_stage("pool_write", _pool_write))
+        # device counters of the last decode/verify step, returned by the
+        # same program as its logits: the routed-expert counts of a trunk
+        # with a dropless MoE share (``vlm.llm_decode_step_paged``), no
+        # leaves otherwise. The in-flight decoder fetches them with the
+        # logits of that step, before it launches the next; they sit here
+        # and not in the stage's return, which every wrapper of the stage
+        # takes as (logits, seg, pool)
+        self.step_counts: Dict = {}
 
     # ---- compile cache ----
 
@@ -352,19 +360,20 @@ class DualStreamExecutor:
     def cloud_decode_rows(self, pool: Dict, page_table, positions, tokens,
                           pos, write_slot
                           ) -> Tuple[np.ndarray, np.ndarray, Dict]:
-        """One paged decode step over all slots. pool {"groups": [kv]}
+        """One paged decode step over all slots. pool {"groups": [kv, ...]}
         with leaves (L, P, page, ...); page_table (slots, n_pages) i32
         (idle rows parked on the trash page); positions
         (slots, n_pages*page) i32 absolute slot positions (-1 empty);
         tokens (slots, 1) i32; pos / write_slot (slots,) i32 — idle rows
         write into the trash page and their outputs are discarded.
-        Returns (answer_logits, seg, new pool)."""
-        return self._decode_paged(self.params, pool,
-                                  jnp.asarray(page_table, jnp.int32),
-                                  jnp.asarray(positions, jnp.int32),
-                                  jnp.asarray(tokens, jnp.int32),
-                                  jnp.asarray(pos, jnp.int32),
-                                  jnp.asarray(write_slot, jnp.int32))
+        Returns (answer_logits, seg, new pool); the same device program
+        leaves the step's counters in ``step_counts``."""
+        logits, seg, pool, self.step_counts = self._decode_paged(
+            self.params, pool, jnp.asarray(page_table, jnp.int32),
+            jnp.asarray(positions, jnp.int32),
+            jnp.asarray(tokens, jnp.int32), jnp.asarray(pos, jnp.int32),
+            jnp.asarray(write_slot, jnp.int32))
+        return logits, seg, pool
 
     def cloud_verify_rows(self, pool: Dict, page_table, positions, tokens,
                           pos, write_slot, chunk_len
@@ -375,14 +384,15 @@ class DualStreamExecutor:
         (slots,) i32 real chunk entries per row (pad entries write to
         the trash page and their logits are discarded). Returns
         (answer_logits (slots, C, V), seg (slots, C, d_sam), new pool)
-        — ``vlm.llm_verify_step_paged`` semantics."""
-        return self._verify_paged(self.params, pool,
-                                  jnp.asarray(page_table, jnp.int32),
-                                  jnp.asarray(positions, jnp.int32),
-                                  jnp.asarray(tokens, jnp.int32),
-                                  jnp.asarray(pos, jnp.int32),
-                                  jnp.asarray(write_slot, jnp.int32),
-                                  jnp.asarray(chunk_len, jnp.int32))
+        — ``vlm.llm_verify_step_paged`` semantics, counters in
+        ``step_counts`` as for ``cloud_decode_rows``."""
+        logits, seg, pool, self.step_counts = self._verify_paged(
+            self.params, pool, jnp.asarray(page_table, jnp.int32),
+            jnp.asarray(positions, jnp.int32),
+            jnp.asarray(tokens, jnp.int32), jnp.asarray(pos, jnp.int32),
+            jnp.asarray(write_slot, jnp.int32),
+            jnp.asarray(chunk_len, jnp.int32))
+        return logits, seg, pool
 
     def cloud_mask(self, feats, seg) -> np.ndarray:
         """<SEG>-conditioned mask decode from stored sam feats (the final

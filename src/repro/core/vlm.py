@@ -89,7 +89,6 @@ def init_lisa(pcfg: LISAPipelineConfig, rng: jax.Array) -> dict:
     ks = jax.random.split(rng, 8)
     llm = pcfg.llm
     d_sam, d_clip, d_llm = pcfg.sam.d_model, pcfg.clip.d_model, llm.d_model
-    llm_spec = stack.layer_groups(llm)[0]
     return {
         "sam": _init_encoder(ks[0], pcfg.sam, pcfg.patch_size, pcfg.sam_tokens),
         "clip": _init_encoder(ks[1], pcfg.clip, pcfg.context_patch_size,
@@ -98,7 +97,9 @@ def init_lisa(pcfg: LISAPipelineConfig, rng: jax.Array) -> dict:
         "llm": {
             "embed": normal_init(ks[3], (llm.vocab_size, d_llm), 0.02,
                                  llm.pdtype),
-            "groups": [stack.init_group(ks[4], llm, llm_spec)],
+            "groups": [stack.init_group(
+                jax.random.fold_in(ks[4], i) if i else ks[4], llm, spec)
+                for i, spec in enumerate(stack.layer_groups(llm))],
             "norm": stack.init_norm(llm),
             "answer_head": fan_in_init(ks[5], (d_llm, llm.vocab_size),
                                        llm.pdtype),
@@ -159,10 +160,10 @@ def sam_tail(params: dict, pcfg: LISAPipelineConfig, x: jax.Array,
 def _llm_trunk(params: dict, pcfg: LISAPipelineConfig, ctx_tokens: jax.Array,
                query_tokens: jax.Array, want_cache: bool = False):
     """Shared full-sequence LLM trunk over [ctx; query]: embed, causal
-    attention stack, final norm. Returns (x (B,S,d), kv_cache_or_None) —
-    the single source of truth for both ``llm_reason`` and
-    ``llm_prefill`` so the fast path and the serving prefill can't
-    diverge."""
+    attention stack (every layer group in turn), final norm. Returns
+    (x (B,S,d), per-group kv caches or None) — the single source of
+    truth for both ``llm_reason`` and ``llm_prefill`` so the fast path
+    and the serving prefill can't diverge."""
     llm = pcfg.llm
     p = params["llm"]
     x_q = jnp.take(p["embed"], query_tokens, axis=0).astype(llm.adtype)
@@ -170,10 +171,14 @@ def _llm_trunk(params: dict, pcfg: LISAPipelineConfig, ctx_tokens: jax.Array,
     B, S, _ = x.shape
     positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
     mask = causal_mask(S)[None]
-    spec = stack.layer_groups(llm)[0]
-    x, _, kv = stack.group_forward(p["groups"][0], llm, spec, x, positions,
-                                   mask, want_cache=want_cache)
-    return stack.apply_norm(x, p["norm"], llm), kv
+    kvs = []
+    for spec, gp in zip(stack.layer_groups(llm), p["groups"],
+                        strict=True):
+        x, _, kv = stack.group_forward(gp, llm, spec, x, positions, mask,
+                                       want_cache=want_cache)
+        kvs.append(kv)
+    return stack.apply_norm(x, p["norm"], llm), \
+        (kvs if want_cache else None)
 
 
 def llm_reason(params: dict, pcfg: LISAPipelineConfig, ctx_tokens: jax.Array,
@@ -223,7 +228,7 @@ def llm_prefill(params: dict, pcfg: LISAPipelineConfig, ctx_tokens: jax.Array,
     pos_arr = jnp.concatenate(
         [jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S)),
          jnp.full((B, W - S), -1, jnp.int32)], axis=1)
-    cache = {"groups": [kv], "positions": pos_arr}
+    cache = {"groups": kv, "positions": pos_arr}
     return answer_logits, seg, cache
 
 
@@ -250,32 +255,53 @@ def llm_prefill_paged(params: dict, pcfg: LISAPipelineConfig,
     paged = jax.tree.map(
         lambda a: a.reshape(a.shape[:2] + (n_pages, page_size)
                             + a.shape[3:]), kv)
-    return answer_logits, seg, {"groups": [paged]}
+    return answer_logits, seg, {"groups": paged}
+
+
+def _trunk_paged(p: dict, llm: ModelConfig, group_fn, x: jax.Array,
+                 positions: jax.Array, pool: Dict, page_table: jax.Array,
+                 write_page: jax.Array, write_off: jax.Array,
+                 mask: jax.Array) -> Tuple[jax.Array, Dict, Dict]:
+    """Every layer group in turn against its part of the page pool
+    (``group_fn``: ``stack.group_decode_paged`` or ``group_verify_paged``).
+    Returns (x normed, new pool, the groups' counters added up)."""
+    kvs, counts = [], {}
+    for spec, gp, kv in zip(stack.layer_groups(llm), p["groups"],
+                            pool["groups"], strict=True):
+        x, kv, c = group_fn(gp, llm, spec, x, positions, kv, page_table,
+                            write_page, write_off, mask)
+        kvs.append(kv)
+        for k, v in c.items():
+            counts[k] = counts[k] + v if k in counts else v
+    return stack.apply_norm(x, p["norm"], llm), {"groups": kvs}, counts
 
 
 def llm_decode_step_paged(params: dict, pcfg: LISAPipelineConfig, pool: Dict,
                           page_table: jax.Array, positions: jax.Array,
                           tokens: jax.Array, pos: jax.Array,
                           write_slot: jax.Array
-                          ) -> Tuple[jax.Array, jax.Array, Dict]:
+                          ) -> Tuple[jax.Array, jax.Array, Dict, Dict]:
     """One in-flight decode step against the shared KV page pool.
 
-    pool {"groups": [kv]} with leaves (L, P, page, ...) — pages shared
-    across every live request; page_table (B, n_pages) i32, every entry
-    a valid page id (idle rows park on the reserved trash page);
+    pool {"groups": [kv, ...]}, one entry per layer group, with leaves
+    (L, P, page, ...) — pages shared across every live request;
+    page_table (B, n_pages) i32, every entry a valid page id (idle rows
+    park on the reserved trash page);
     positions (B, n_pages*page) i32 absolute position stored in each
     virtual slot (-1 empty — the caller owns this bookkeeping, it is
     append-only and deterministic); tokens (B,1) i32; pos (B,) i32
     absolute positions of the new tokens; write_slot (B,) i32 virtual
     slot receiving each row's token. Returns (answer_logits (B,V),
-    seg (B,d_sam), new pool). Token-exact with the contiguous
+    seg (B,d_sam), new pool, counts) — ``counts`` the step's routed-expert
+    counters (``stack.group_decode_paged``), empty for a trunk without a
+    dropless MoE share. Token-exact with the contiguous
     ``llm_decode_step``: the gathered virtual sequence preserves
     ascending position order and masked slots contribute exactly zero.
     """
     llm = pcfg.llm
     p = params["llm"]
     B = tokens.shape[0]
-    page = pool["groups"][0]["k"].shape[2]
+    page = jax.tree.leaves(pool)[0].shape[2]
     x = jnp.take(p["embed"], tokens, axis=0).astype(llm.adtype)
     pos = jnp.asarray(pos, jnp.int32)
     write_slot = jnp.asarray(write_slot, jnp.int32)
@@ -285,20 +311,18 @@ def llm_decode_step_paged(params: dict, pcfg: LISAPipelineConfig, pool: Dict,
     page_table = jnp.asarray(page_table, jnp.int32)
     write_page = page_table[rows, write_slot // page]
     write_off = write_slot % page
-    spec = stack.layer_groups(llm)[0]
-    x, kv = stack.group_decode_paged(p["groups"][0], llm, spec, x,
-                                     pos[:, None], pool["groups"][0],
-                                     page_table, write_page, write_off, mask)
-    x = stack.apply_norm(x, p["norm"], llm)
+    x, pool, counts = _trunk_paged(p, llm, stack.group_decode_paged, x,
+                                   pos[:, None], pool, page_table,
+                                   write_page, write_off, mask)
     answer_logits, seg = _llm_outputs(params, x[:, -1])
-    return answer_logits, seg, {"groups": [kv]}
+    return answer_logits, seg, pool, counts
 
 
 def llm_verify_step_paged(params: dict, pcfg: LISAPipelineConfig, pool: Dict,
                           page_table: jax.Array, positions: jax.Array,
                           tokens: jax.Array, pos: jax.Array,
                           write_slot: jax.Array, chunk_len: jax.Array
-                          ) -> Tuple[jax.Array, jax.Array, Dict]:
+                          ) -> Tuple[jax.Array, jax.Array, Dict, Dict]:
     """One speculative *verify* step: a chunk of C tokens per row — the
     row's last accepted token followed by drafted continuations — scored
     through the serving model in a single paged multi-token pass.
@@ -321,12 +345,13 @@ def llm_verify_step_paged(params: dict, pcfg: LISAPipelineConfig, pool: Dict,
     after consuming chunk token i (column 0 of a chunk_len=1 call
     matches ``llm_decode_step_paged`` on the same token), and seg[:, i]
     is the <SEG> read at chunk position i (the final accepted position
-    supplies ``llm_generate``'s end-of-answer embedding)."""
+    supplies ``llm_generate``'s end-of-answer embedding); counts as in
+    ``llm_decode_step_paged``."""
     from repro.core.paging import TRASH_PAGE
     llm = pcfg.llm
     p = params["llm"]
     B, C = tokens.shape
-    page = pool["groups"][0]["k"].shape[2]
+    page = jax.tree.leaves(pool)[0].shape[2]
     n_slots = positions.shape[1]
     x = jnp.take(p["embed"], tokens, axis=0).astype(llm.adtype)
     rows = jnp.arange(B)[:, None]
@@ -346,13 +371,11 @@ def llm_verify_step_paged(params: dict, pcfg: LISAPipelineConfig, pool: Dict,
     write_page = jnp.where(valid, page_table[rows, ws_in // page],
                            TRASH_PAGE)
     write_off = ws_in % page
-    spec = stack.layer_groups(llm)[0]
-    x, kv = stack.group_verify_paged(p["groups"][0], llm, spec, x, pos_c,
-                                     pool["groups"][0], page_table,
-                                     write_page, write_off, mask)
-    x = stack.apply_norm(x, p["norm"], llm)
+    x, pool, counts = _trunk_paged(p, llm, stack.group_verify_paged, x,
+                                   pos_c, pool, page_table, write_page,
+                                   write_off, mask)
     answer_logits, seg = _llm_outputs(params, x)
-    return answer_logits, seg, {"groups": [kv]}
+    return answer_logits, seg, pool, counts
 
 
 def llm_decode_step(params: dict, pcfg: LISAPipelineConfig, cache: Dict,
@@ -383,12 +406,15 @@ def llm_decode_step(params: dict, pcfg: LISAPipelineConfig, cache: Dict,
         pos_arr = cache["positions"].at[jnp.arange(B), slot].set(pos)
         mask = cache_mask(pos_arr, pos[:, None], llm.sliding_window)
         positions = pos[:, None]
-    spec = stack.layer_groups(llm)[0]
-    x, kv = stack.group_decode(p["groups"][0], llm, spec, x, positions,
-                               cache["groups"][0], slot, mask)
+    kvs = []
+    for spec, gp, kv in zip(stack.layer_groups(llm), p["groups"],
+                            cache["groups"], strict=True):
+        x, kv = stack.group_decode(gp, llm, spec, x, positions, kv, slot,
+                                   mask)
+        kvs.append(kv)
     x = stack.apply_norm(x, p["norm"], llm)
     answer_logits, seg = _llm_outputs(params, x[:, -1])
-    return answer_logits, seg, {"groups": [kv], "positions": pos_arr}
+    return answer_logits, seg, {"groups": kvs, "positions": pos_arr}
 
 
 def llm_generate(params: dict, pcfg: LISAPipelineConfig, ctx_tokens: jax.Array,

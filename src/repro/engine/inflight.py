@@ -61,6 +61,7 @@ from collections import deque
 from dataclasses import dataclass, fields
 from typing import Any, Callable, Deque, Dict, List, Optional, Tuple
 
+import jax
 import numpy as np
 
 from repro.core import packets as pk
@@ -87,6 +88,17 @@ class DecodeTotals:
     prefill_tokens: int = 0  # prefix tokens those prefills ran
     sam_tails: int = 0       # SAM tails run (Insight admissions)
     masks: int = 0           # masks decoded
+    # a trunk with a dropless MoE share (``moe.moe_held``), counted on the
+    # device by the decode/verify stage over its live tokens (not idle
+    # rows or pad entries), all MoE layers: routed picks that landed on
+    # held experts, and held (layer, expert) pairs with at least one pick
+    expert_picks: int = 0
+    experts_hit: int = 0
+
+    def add_counts(self, counts: Dict[str, Any]) -> None:
+        """Fold one step's device counters (named as the fields) in."""
+        for name, value in counts.items():
+            setattr(self, name, getattr(self, name) + int(value))
 
     def merge(self, other: "DecodeTotals") -> None:
         for f in fields(self):
@@ -568,7 +580,7 @@ class InflightDecoder:
         except CloudStageError as e:
             return self._fail_step(e)
         with span("inflight.step.fetch"):
-            logits, seg = np.asarray(logits), np.asarray(seg)
+            logits, seg = self._fetch(logits, seg)
         # the step as the host sees it: launch, device and the copy back
         if wc is not None and self._metrics is not None:
             self._metrics.histogram("decode_step_s").observe(wc() - w0)
@@ -656,7 +668,7 @@ class InflightDecoder:
         except CloudStageError as e:
             return self._fail_step(e)
         with span("inflight.step.fetch"):
-            logits, seg = np.asarray(logits), np.asarray(seg)
+            logits, seg = self._fetch(logits, seg)
         if wc is not None and self._metrics is not None:
             self._metrics.histogram("verify_step_s").observe(wc() - w0)
         live = len(self.active)
@@ -732,6 +744,16 @@ class InflightDecoder:
         if finished:
             self.admit()
         return finished
+
+    def _fetch(self, logits, seg):
+        """A step's logits and <SEG> states on the host. The device
+        counters the same program left on the executor (``step_counts``;
+        no other stage call comes between the launch and this fetch)
+        come back in the same copy and go into the totals."""
+        logits, seg, counts = jax.device_get(
+            (logits, seg, getattr(self.executor, "step_counts", {})))
+        self.totals.add_counts(counts)
+        return logits, seg
 
     def _grow_private(self, slot: int, st: _SlotState, tokens: int) -> None:
         """Extend one row's private decode pages to cover ``tokens``

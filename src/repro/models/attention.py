@@ -8,7 +8,8 @@ Cache layout (per layer, stacked along a leading layer axis by the stack):
   GQA: {"k": (B, W, K, hd), "v": (B, W, K, hd)}      — k stored post-RoPE
   MLA: {"ckv": (B, W, r_kv), "krope": (B, W, d_r)}   — the latent cache that
        makes DeepSeek-style decode memory-light (this *is* MLA's bottleneck
-       affinity noted in DESIGN.md).
+       affinity noted in DESIGN.md); the paged pool holds the same two
+       leaves a page at a time, (P, page, r_kv) and (P, page, d_r).
 Slot-position bookkeeping ((B?, W) absolute positions) lives at the model
 level and arrives here as a pre-computed additive mask.
 """
@@ -20,7 +21,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.models.common import (NEG_INF, apply_mrope, apply_rope,
-                                 fan_in_init, linear, zeros_init)
+                                 apply_yarn_rope, fan_in_init, linear,
+                                 yarn_mscale, zeros_init)
 from repro.models.config import ModelConfig
 
 # ---------------------------------------------------------------------------
@@ -54,10 +56,10 @@ def init_mla(rng: jax.Array, cfg: ModelConfig) -> dict:
     dt = cfg.pdtype
     return {
         "wq_a": fan_in_init(ks[0], (d, m.q_lora_rank), dt),
-        "q_norm": jnp.ones((m.q_lora_rank,), dt),
+        "q_norm": {"w": jnp.ones((m.q_lora_rank,), dt)},
         "wq_b": fan_in_init(ks[1], (m.q_lora_rank, H * qk), dt),
         "wkv_a": fan_in_init(ks[2], (d, m.kv_lora_rank + m.qk_rope_head_dim), dt),
-        "kv_norm": jnp.ones((m.kv_lora_rank,), dt),
+        "kv_norm": {"w": jnp.ones((m.kv_lora_rank,), dt)},
         "wkv_b": fan_in_init(ks[3], (m.kv_lora_rank,
                                      H * (m.qk_nope_head_dim + m.v_head_dim)), dt),
         "wo": fan_in_init(ks[4], (H * m.v_head_dim, d), dt),
@@ -297,21 +299,41 @@ def gqa_empty_cache(cfg: ModelConfig, batch: int, width: int) -> dict:
 # ---------------------------------------------------------------------------
 
 
+def _mla_rope(cfg: ModelConfig, x: jax.Array,
+              positions: jax.Array) -> jax.Array:
+    y = cfg.mla.yarn
+    if y is None:
+        return apply_rope(x, positions, cfg.rope_theta)
+    return apply_yarn_rope(x, positions, cfg.rope_theta, y)
+
+
+def _mla_scale(cfg: ModelConfig) -> jax.Array:
+    """1/sqrt(qk_nope + qk_rope), times YaRN's mscale(mscale_all_dim)^2."""
+    m = cfg.mla
+    scale = 1.0 / jnp.sqrt(float(m.qk_nope_head_dim + m.qk_rope_head_dim))
+    if m.yarn is not None and m.yarn.mscale_all_dim:
+        scale = scale * yarn_mscale(m.yarn.factor,
+                                    m.yarn.mscale_all_dim) ** 2
+    return scale
+
+
 def _mla_qkv_full(p: dict, cfg: ModelConfig, x: jax.Array, positions: jax.Array):
     from repro.models.common import rms_norm
     m = cfg.mla
     B, S, _ = x.shape
     H = cfg.num_heads
     qk_n, qk_r, dv = m.qk_nope_head_dim, m.qk_rope_head_dim, m.v_head_dim
-    q = linear(rms_norm(linear(x, p["wq_a"]), p["q_norm"], cfg.norm_eps), p["wq_b"])
+    q = linear(rms_norm(linear(x, p["wq_a"]), p["q_norm"]["w"], cfg.norm_eps),
+               p["wq_b"])
     q = q.reshape(B, S, H, qk_n + qk_r)
     q_nope, q_rope = q[..., :qk_n], q[..., qk_n:]
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
+    q_rope = _mla_rope(cfg, q_rope, positions)
 
     kv_a = linear(x, p["wkv_a"])
-    ckv = rms_norm(kv_a[..., :m.kv_lora_rank], p["kv_norm"], cfg.norm_eps)
+    ckv = rms_norm(kv_a[..., :m.kv_lora_rank], p["kv_norm"]["w"],
+                   cfg.norm_eps)
     k_rope = kv_a[..., m.kv_lora_rank:].reshape(B, S, 1, qk_r)
-    k_rope = apply_rope(k_rope, positions, cfg.rope_theta)[:, :, 0, :]
+    k_rope = _mla_rope(cfg, k_rope, positions)[:, :, 0, :]
     return q_nope, q_rope, ckv, k_rope
 
 
@@ -324,7 +346,7 @@ def mla_full(p: dict, cfg: ModelConfig, x: jax.Array, positions: jax.Array,
     q_nope, q_rope, ckv, k_rope = _mla_qkv_full(p, cfg, x, positions)
     kv = linear(ckv, p["wkv_b"]).reshape(B, S, H, qk_n + dv)
     k_nope, v = kv[..., :qk_n], kv[..., qk_n:]
-    scale = 1.0 / jnp.sqrt(float(qk_n + qk_r))
+    scale = _mla_scale(cfg)
 
     def attend(qn, qr, mb):
         scores = (jnp.einsum("bshn,bthn->bhst", qn.astype(jnp.float32),
@@ -356,34 +378,82 @@ def mla_full(p: dict, cfg: ModelConfig, x: jax.Array, positions: jax.Array,
     return out, {"ckv": ckv, "krope": k_rope}
 
 
+def _mla_absorbed(p: dict, cfg: ModelConfig, q_nope: jax.Array,
+                  q_rope: jax.Array, ckv: jax.Array, krope: jax.Array,
+                  mask: jax.Array) -> jax.Array:
+    """Latent attention with the up-projections absorbed: ``W_uk`` goes
+    into the query and ``W_uv`` after the latent output, so the keys and
+    values are the cached latents themselves. q_nope (B,S,H,n), q_rope
+    (B,S,H,r'); ckv (B,W,r), krope (B,W,r'); mask (B,S,W) additive.
+    Operands stay in their stored type, products accumulate in float32.
+    Returns (B, S, H, dv)."""
+    m = cfg.mla
+    H = cfg.num_heads
+    qk_n, dv = m.qk_nope_head_dim, m.v_head_dim
+    f32 = jnp.float32
+    wkv_b = p["wkv_b"].reshape(m.kv_lora_rank, H, qk_n + dv)
+    w_uk = wkv_b[..., :qk_n].astype(q_nope.dtype)          # (r, H, qk_n)
+    w_uv = wkv_b[..., qk_n:].astype(q_nope.dtype)          # (r, H, dv)
+    q_lat = jnp.einsum("bshn,rhn->bshr", q_nope, w_uk,
+                       preferred_element_type=f32).astype(ckv.dtype)
+    scale = _mla_scale(cfg)
+    scores = (jnp.einsum("bshr,bwr->bhsw", q_lat, ckv,
+                         preferred_element_type=f32)
+              + jnp.einsum("bshr,bwr->bhsw", q_rope.astype(krope.dtype),
+                           krope, preferred_element_type=f32)) * scale
+    probs = jax.nn.softmax(scores + mask[:, None], axis=-1)
+    o_lat = jnp.einsum("bwr,bhsw->bshr", ckv, probs.astype(ckv.dtype),
+                       preferred_element_type=f32).astype(q_nope.dtype)
+    return jnp.einsum("bshr,rhv->bshv", o_lat, w_uv,
+                      preferred_element_type=f32).astype(q_nope.dtype)
+
+
 def mla_decode(p: dict, cfg: ModelConfig, x: jax.Array, positions: jax.Array,
                cache: dict, slot: jax.Array, mask: jax.Array) -> Tuple[jax.Array, dict]:
     """Absorbed-matmul MLA decode: scores are computed in the latent space so
-    the cache stays (r_kv + d_r) per token — the memory win of MLA."""
-    m = cfg.mla
+    the cache stays (r_kv + d_r) per token — the memory win of MLA. slot is
+    a scalar (shared ring slot) or (B,) per-row slots; mask (B, W)."""
     B, S, _ = x.shape
-    H = cfg.num_heads
-    qk_n, qk_r, dv = m.qk_nope_head_dim, m.qk_rope_head_dim, m.v_head_dim
     q_nope, q_rope, ckv_new, krope_new = _mla_qkv_full(p, cfg, x, positions)
-    ckv = jax.lax.dynamic_update_slice_in_dim(cache["ckv"], ckv_new, slot, axis=1)
-    krope = jax.lax.dynamic_update_slice_in_dim(cache["krope"], krope_new, slot, axis=1)
-
-    wkv_b = p["wkv_b"].reshape(m.kv_lora_rank, H, qk_n + dv)
-    w_uk = wkv_b[..., :qk_n]                       # (r, H, qk_n)
-    w_uv = wkv_b[..., qk_n:]                       # (r, H, dv)
-    # absorb k up-projection into the query
-    q_lat = jnp.einsum("bshn,rhn->bshr", q_nope.astype(jnp.float32),
-                       w_uk.astype(jnp.float32))   # (B,1,H,r)
-    scale = 1.0 / jnp.sqrt(float(qk_n + qk_r))
-    scores = (jnp.einsum("bshr,bwr->bhsw", q_lat, ckv.astype(jnp.float32))
-              + jnp.einsum("bshr,bwr->bhsw", q_rope.astype(jnp.float32),
-                           krope.astype(jnp.float32))) * scale
-    scores = scores + mask[:, None, None, :]
-    probs = jax.nn.softmax(scores, axis=-1)
-    o_lat = jnp.einsum("bhsw,bwr->bshr", probs, ckv.astype(jnp.float32))
-    out = jnp.einsum("bshr,rhv->bshv", o_lat, w_uv.astype(jnp.float32)).astype(x.dtype)
-    out = linear(out.reshape(B, S, H * dv), p["wo"])
+    if jnp.ndim(slot) == 0:
+        ckv = jax.lax.dynamic_update_slice_in_dim(cache["ckv"], ckv_new,
+                                                  slot, axis=1)
+        krope = jax.lax.dynamic_update_slice_in_dim(cache["krope"],
+                                                    krope_new, slot, axis=1)
+    else:                               # per-row scatter into the ring
+        rows = jnp.arange(B)
+        ckv = cache["ckv"].at[rows, slot].set(ckv_new[:, 0])
+        krope = cache["krope"].at[rows, slot].set(krope_new[:, 0])
+    out = _mla_absorbed(p, cfg, q_nope, q_rope, ckv, krope, mask[:, None, :])
+    out = linear(out.reshape(B, S, -1), p["wo"])
     return out, {"ckv": ckv, "krope": krope}
+
+
+def mla_paged(p: dict, cfg: ModelConfig, x: jax.Array, positions: jax.Array,
+              pool: dict, page_table: jax.Array, write_page: jax.Array,
+              write_off: jax.Array, mask: jax.Array) -> Tuple[jax.Array, dict]:
+    """MLA against the shared page pool, for decode and speculative
+    verify alike: the latent ``ckv`` (P, page, r) and ``krope``
+    (P, page, r') pages are what the pool holds, (r + r') values a token
+    a layer. x (B, S, d) — one token a row (decode: write_page/write_off
+    (B,), mask (B, n_pages*page)) or a chunk of S (verify: (B, S) and
+    (B, S, n_pages*page)). The new latents land in the pool before
+    attention, as ``gqa_decode_paged``/``gqa_verify_paged`` do, and the
+    absorbed attention reads each row's pages through its page table.
+    Returns (out, new pool)."""
+    B, S, _ = x.shape
+    q_nope, q_rope, ckv_new, krope_new = _mla_qkv_full(p, cfg, x, positions)
+    if write_page.ndim == 1:
+        ckv_new, krope_new, mask = ckv_new[:, 0], krope_new[:, 0], \
+            mask[:, None, :]
+    ckv_pool = pool["ckv"].at[write_page, write_off].set(ckv_new)
+    krope_pool = pool["krope"].at[write_page, write_off].set(krope_new)
+    n, page = page_table.shape[1], ckv_pool.shape[1]
+    ckv = ckv_pool[page_table].reshape(B, n * page, -1)
+    krope = krope_pool[page_table].reshape(B, n * page, -1)
+    out = _mla_absorbed(p, cfg, q_nope, q_rope, ckv, krope, mask)
+    out = linear(out.reshape(B, S, -1), p["wo"])
+    return out, {"ckv": ckv_pool, "krope": krope_pool}
 
 
 def mla_empty_cache(cfg: ModelConfig, batch: int, width: int) -> dict:
@@ -408,10 +478,6 @@ def attn_full(p, cfg: ModelConfig, x, positions, mask):
 
 def attn_decode(p, cfg: ModelConfig, x, positions, cache, slot, mask):
     if cfg.attn_type == "mla":
-        if jnp.ndim(slot) != 0:
-            raise NotImplementedError(
-                "per-row decode slots (in-flight batching) are only "
-                "implemented for the GQA cache layout")
         return mla_decode(p, cfg, x, positions, cache, slot, mask)
     return gqa_decode(p, cfg, x, positions, cache, slot, mask)
 
@@ -419,9 +485,8 @@ def attn_decode(p, cfg: ModelConfig, x, positions, cache, slot, mask):
 def attn_decode_paged(p, cfg: ModelConfig, x, positions, pool, page_table,
                       write_page, write_off, mask):
     if cfg.attn_type == "mla":
-        raise NotImplementedError(
-            "the paged KV pool is only implemented for the GQA cache "
-            "layout (MLA's latent cache pages differently)")
+        return mla_paged(p, cfg, x, positions, pool, page_table, write_page,
+                         write_off, mask)
     return gqa_decode_paged(p, cfg, x, positions, pool, page_table,
                             write_page, write_off, mask)
 
@@ -429,9 +494,8 @@ def attn_decode_paged(p, cfg: ModelConfig, x, positions, pool, page_table,
 def attn_verify_paged(p, cfg: ModelConfig, x, positions, pool, page_table,
                       write_page, write_off, mask):
     if cfg.attn_type == "mla":
-        raise NotImplementedError(
-            "the paged KV pool is only implemented for the GQA cache "
-            "layout (MLA's latent cache pages differently)")
+        return mla_paged(p, cfg, x, positions, pool, page_table, write_page,
+                         write_off, mask)
     return gqa_verify_paged(p, cfg, x, positions, pool, page_table,
                             write_page, write_off, mask)
 

@@ -116,6 +116,43 @@ def apply_rope(x: jax.Array, positions: jax.Array, theta: float = 10000.0) -> ja
     return _apply_angles(x, angles)
 
 
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention factor: 0.1 * mscale * ln(factor) + 1 (1 at
+    factor <= 1)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(dim: int, theta: float, yarn) -> jax.Array:
+    """The rotary inverse frequencies (dim//2,) under YaRN
+    (``config.YarnConfig``): the band of dims between the ones that turn
+    ``beta_fast`` and ``beta_slow`` times over the original context goes
+    linearly from the plain frequency to the plain one over ``factor``."""
+    half = dim // 2
+
+    def turns(n):                       # the dim that turns n times
+        return dim * math.log(yarn.original_max_position
+                              / (n * 2 * math.pi)) / (2 * math.log(theta))
+
+    low = max(math.floor(turns(yarn.beta_fast)), 0)
+    high = min(math.ceil(turns(yarn.beta_slow)), dim - 1)
+    ramp = jnp.clip((jnp.arange(half, dtype=jnp.float32) - low)
+                    / max(high - low, 1e-3), 0.0, 1.0)
+    plain = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+    return plain / yarn.factor * ramp + plain * (1.0 - ramp)
+
+
+def apply_yarn_rope(x: jax.Array, positions: jax.Array, theta: float,
+                    yarn) -> jax.Array:
+    """``apply_rope`` with YaRN's frequencies and cos/sin factor."""
+    angles = positions.astype(jnp.float32)[..., None] \
+        * yarn_inv_freq(x.shape[-1], theta, yarn)
+    out = _apply_angles(x, angles)
+    amp = yarn_mscale(yarn.factor, yarn.mscale) \
+        / yarn_mscale(yarn.factor, yarn.mscale_all_dim)
+    return out if amp == 1.0 else (out.astype(jnp.float32)
+                                   * amp).astype(x.dtype)
+
+
 def apply_mrope(x: jax.Array, positions: jax.Array,
                 sections: Sequence[int], theta: float = 10000.0) -> jax.Array:
     """Multimodal RoPE (Qwen2-VL). positions: (3, B, S) = (t, h, w) streams.

@@ -15,6 +15,23 @@ import jax.numpy as jnp
 
 
 @dataclass(frozen=True)
+class YarnConfig:
+    """YaRN's extension of RoPE (DeepSeek-V3's ``rope_scaling``): rotary
+    frequencies that turn fewer than ``beta_slow`` times over
+    ``original_max_position`` positions are divided by ``factor``, those
+    that turn more than ``beta_fast`` times are kept, a linear ramp lies
+    between; cos/sin are scaled by mscale(``mscale``) / mscale(
+    ``mscale_all_dim``) and the softmax by mscale(``mscale_all_dim``)^2,
+    with mscale(m) = 0.1 m ln(factor) + 1."""
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+@dataclass(frozen=True)
 class MLAConfig:
     """Multi-head Latent Attention (DeepSeek-V2/V3, MiniCPM3)."""
     q_lora_rank: int
@@ -22,6 +39,8 @@ class MLAConfig:
     qk_nope_head_dim: int
     qk_rope_head_dim: int
     v_head_dim: int
+    # YaRN on the rotary part (None: plain RoPE)
+    yarn: Optional[YarnConfig] = None
 
 
 @dataclass(frozen=True)
@@ -34,11 +53,31 @@ class MoEConfig:
     capacity_factor: float = 1.25
     router_aux_weight: float = 0.01
     # "einsum" = GShard-faithful one-hot dispatch (baseline);
-    # "scatter" = sort-based dispatch that avoids the (T,E,C) temp (§Perf)
+    # "scatter" = sort-based dispatch that avoids the (T,E,C) temp (§Perf);
+    # "dropless" = the serving share: no capacity, only the held experts
+    # computed (``held_experts`` below)
     dispatch: str = "einsum"
     # layers [0, first_k_dense) use a plain dense FFN (DeepSeek-V3 style)
     first_k_dense: int = 0
     d_ff_dense: int = 0
+    # routing: "softmax" over every expert, or "sigmoid" per expert with
+    # a per-expert correction bias used only to choose (DeepSeek-V3's
+    # noaux_tc); experts fall in ``n_group`` groups, each scored by the
+    # sum of its top-2, and picks come from the ``topk_group`` best; the
+    # normalised gates are scaled by ``routed_scaling``
+    scoring: str = "softmax"
+    n_group: int = 1
+    topk_group: int = 1
+    routed_scaling: float = 1.0
+    # expert parallelism: this layer holds experts [held_first,
+    # held_first + held_experts) of the router's ``num_experts`` (0 holds
+    # them all); picks on the others contribute nothing here
+    held_experts: int = 0
+    held_first: int = 0
+
+    @property
+    def held(self) -> int:
+        return self.held_experts or self.num_experts
 
 
 @dataclass(frozen=True)

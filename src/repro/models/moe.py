@@ -7,12 +7,18 @@ The §Perf hillclimb iterates on its dispatch-FLOPs overhead.
 
 Supports DeepSeek-V3 topology: ``num_shared_experts`` always-on experts,
 ``first_k_dense`` leading dense layers, normalized top-k gates, and a
-load-balance auxiliary loss.
+load-balance auxiliary loss; and its routing (``route``): sigmoid scores,
+a correction bias used only to choose, group-limited top-k, scaled gates.
+
+``dispatch="dropless"`` is the serving layer of expert parallelism
+(``moe_held``): the layer holds some of the router's experts, routes over
+all of them, drops nothing and computes its own experts' part. The
+capacity dispatches above hold every expert.
 """
 from __future__ import annotations
 
 import math
-from typing import Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -58,10 +64,14 @@ def init_moe(rng: jax.Array, cfg: ModelConfig) -> dict:
     dt = cfg.pdtype
     p = {
         "router": fan_in_init(ks[0], (d, E), jnp.float32),
-        "w_gate": fan_in_init(ks[1], (E, d, f), dt),
-        "w_up": fan_in_init(ks[2], (E, d, f), dt),
-        "w_down": fan_in_init(ks[3], (E, f, d), dt),
+        "w_gate": fan_in_init(ks[1], (m.held, d, f), dt),
+        "w_up": fan_in_init(ks[2], (m.held, d, f), dt),
+        "w_down": fan_in_init(ks[3], (m.held, f, d), dt),
     }
+    if m.scoring == "sigmoid":
+        # the correction bias (``e_score_correction_bias``), a bias of
+        # the router's choice only
+        p["correction"] = {"b": jnp.zeros((E,), jnp.float32)}
     if m.num_shared_experts:
         p["shared"] = init_dense_mlp(
             ks[4], cfg, m.d_ff_shared * m.num_shared_experts)
@@ -74,14 +84,50 @@ def capacity(cfg: ModelConfig, num_tokens: int) -> int:
     return max(4, min(num_tokens, c))
 
 
+def route(p: dict, cfg: ModelConfig, xt: jax.Array):
+    """Top-k routing over every expert of the router, in float32.
+    Returns (scores (T, E), gate_vals (T, k), gate_idx (T, k)).
+
+    Softmax scoring (the training configs) normalises over all experts
+    and takes the top-k of one group. Sigmoid scoring (DeepSeek-V3)
+    scores each expert alone at full precision; the correction bias and
+    the group limit shape only the choice: groups are ranked by the sum
+    of their two best biased scores and picks come from the
+    ``topk_group`` best groups; the gates are the unbiased scores of the
+    picks, normalised and scaled by ``routed_scaling``."""
+    m = cfg.moe
+    E, k, G = m.num_experts, m.top_k, m.n_group
+    if m.scoring == "softmax":
+        if G > 1:
+            raise ValueError("group-limited routing needs sigmoid scoring")
+        scores = jax.nn.softmax(xt.astype(jnp.float32) @ p["router"],
+                                axis=-1)                          # (T, E)
+        gate_vals, gate_idx = jax.lax.top_k(scores, k)            # (T, k)
+        gate_vals = gate_vals / (jnp.sum(gate_vals, axis=-1, keepdims=True)
+                                 + 1e-9)
+        return scores, gate_vals, gate_idx
+    scores = jax.nn.sigmoid(jnp.dot(xt.astype(jnp.float32), p["router"],
+                                    precision=jax.lax.Precision.HIGHEST))
+    choice = scores + p["correction"]["b"]
+    if G > 1:
+        grouped = choice.reshape(-1, G, E // G)
+        group_score = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)
+        _, keep = jax.lax.top_k(group_score, m.topk_group)        # (T, g)
+        kept = jnp.sum(jax.nn.one_hot(keep, G, dtype=jnp.bool_), axis=1)
+        choice = jnp.where(kept[:, :, None], grouped,
+                           -jnp.inf).reshape(-1, E)
+    _, gate_idx = jax.lax.top_k(choice, k)                        # (T, k)
+    gate_vals = jnp.take_along_axis(scores, gate_idx, axis=-1)
+    gate_vals = gate_vals / (jnp.sum(gate_vals, axis=-1, keepdims=True)
+                             + 1e-20)
+    return scores, gate_vals * m.routed_scaling, gate_idx
+
+
 def _router(p: dict, cfg: ModelConfig, xt: jax.Array):
     """Shared routing: returns (gate_vals (T,k), gate_idx (T,k), aux)."""
     m = cfg.moe
-    E, k = m.num_experts, m.top_k
-    logits = (xt.astype(jnp.float32) @ p["router"])               # (T, E)
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate_vals, gate_idx = jax.lax.top_k(probs, k)                 # (T, k)
-    gate_vals = gate_vals / (jnp.sum(gate_vals, axis=-1, keepdims=True) + 1e-9)
+    E = m.num_experts
+    probs, gate_vals, gate_idx = route(p, cfg, xt)
     # load-balance auxiliary loss (Switch-style)
     me = jnp.mean(probs, axis=0)                                  # (E,)
     onehot_any = jax.nn.one_hot(gate_idx, E, dtype=jnp.float32)   # (T,k,E)
@@ -218,10 +264,70 @@ def _moe_grouped(p: dict, cfg: ModelConfig, x: jax.Array, gate_vals, gate_idx
     return out.reshape(B * S, d)
 
 
+EXPERT_WEIGHTS = ("w_gate", "w_up", "w_down")
+
+
+def moe_held(p: dict, cfg: ModelConfig, x: jax.Array,
+             layer: Optional[jax.Array] = None,
+             live: Optional[jax.Array] = None
+             ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """The serving share of an MoE layer: x (B, S, d) -> (out, counts).
+
+    Routes every token over all of the router's experts, then computes
+    the part of the result that this layer's held experts give, for
+    every token routed to them: dropless, no capacity. Picks on experts
+    held elsewhere contribute nothing here (on one chip the exchange that
+    would carry them is absent); the shared expert is added whole. A held
+    expert no token picked is skipped, its weights unread. ``counts``:
+    ``expert_picks``, the picks that landed on held experts, and
+    ``experts_hit``, the held experts with at least one pick (int32).
+
+    With ``layer``, the expert weights in ``p`` are a group's stacks
+    (L, H, ...) and each expert is sliced by (layer, expert) inside the
+    branch that computes it: a layer scan that sliced the stacks itself
+    would copy a layer's every expert before the branches run.
+
+    ``live`` (B, S) bool marks the tokens someone is served: the others
+    (a decode step's idle rows, a verify chunk's pad entries) get the
+    shared expert alone, and neither count nor wake a held expert."""
+    m = cfg.moe
+    B, S, d = x.shape
+    xt = x.reshape(B * S, d)
+    _, gate_vals, gate_idx = route(p, cfg, xt)
+    local = gate_idx - m.held_first
+    local = jnp.where((local >= 0) & (local < m.held), local, -1)
+    if live is not None:
+        local = jnp.where(live.reshape(B * S, 1), local, -1)
+    onehot = jax.nn.one_hot(local, m.held, dtype=jnp.float32)    # (T,k,H)
+    gates = jnp.einsum("tk,tkh->th", gate_vals, onehot)          # (T, H)
+    picks = jnp.sum(onehot, axis=(0, 1)).astype(jnp.int32)       # (H,)
+
+    def expert(acc, e):
+        def run(acc):
+            w = {k: p[k][e] if layer is None else p[k][layer, e]
+                 for k in EXPERT_WEIGHTS}
+            h = silu(linear(xt, w["w_gate"])) * linear(xt, w["w_up"])
+            y = linear(h, w["w_down"]).astype(jnp.float32)
+            return acc + y * gates[:, e, None]
+        return jax.lax.cond(picks[e] > 0, run, lambda a: a, acc), None
+
+    out, _ = jax.lax.scan(expert, jnp.zeros((B * S, d), jnp.float32),
+                          jnp.arange(m.held))
+    out = out.astype(x.dtype)
+    if m.num_shared_experts:
+        out = out + dense_mlp(p["shared"], cfg, xt)
+    counts = {"expert_picks": jnp.sum(picks),
+              "experts_hit": jnp.sum(picks > 0).astype(jnp.int32)}
+    return out.reshape(B, S, d), counts
+
+
 def moe_mlp(p: dict, cfg: ModelConfig, x: jax.Array) -> Tuple[jax.Array, jax.Array]:
     """x: (B, S, d) -> (out, aux_loss). Capacity-dropped tokens fall back to
-    the shared expert (if any) / residual."""
+    the shared expert (if any) / residual. The dropless serving share
+    (``moe_held``) has no auxiliary loss."""
     m = cfg.moe
+    if m.dispatch == "dropless":
+        return moe_held(p, cfg, x)[0], jnp.float32(0.0)
     B, S, d = x.shape
     T = B * S
     C = capacity(cfg, T)

@@ -141,11 +141,35 @@ def _dense_block_full(p, cfg, x, positions, mask):
     return x, kv
 
 
-def _moe_block_full(p, cfg, x, positions, mask):
+def _held_stacks(params: Any, cfg: ModelConfig, spec: GroupSpec):
+    """A dropless MoE group's expert weights, kept stacked outside its
+    layer scan (``moe.moe_held``'s ``layer``): (params without them,
+    the stacks), or (params, None) for any other group."""
+    if spec.kind != "moe" or cfg.moe.dispatch != "dropless":
+        return params, None
+    moe = dict(params["moe"])
+    stacks = {k: moe.pop(k) for k in moe_lib.EXPERT_WEIGHTS}
+    return dict(params, moe=moe), stacks
+
+
+def _moe_ffn(p, cfg, x, stacks=None, layer=None, live=None):
+    """(out, aux, counts) of an MoE layer's FFN; ``stacks``/``layer`` as
+    ``_held_stacks`` gives them (the dropless share, no aux loss), and
+    ``live`` as ``moe.moe_held`` takes it."""
+    if stacks is None:
+        y, aux = moe_lib.moe_mlp(p["moe"], cfg, x)
+        return y, aux, {}
+    y, counts = moe_lib.moe_held(dict(p["moe"], **stacks), cfg, x, layer,
+                                 live)
+    return y, jnp.float32(0.0), counts
+
+
+def _moe_block_full(p, cfg, x, positions, mask, stacks=None, layer=None):
     h, kv = attention.attn_full(p["attn"], cfg, apply_norm(x, p["norm1"], cfg),
                                 positions, mask)
     x = x + h
-    y, aux = moe_lib.moe_mlp(p["moe"], cfg, apply_norm(x, p["norm2"], cfg))
+    y, aux, _ = _moe_ffn(p, cfg, apply_norm(x, p["norm2"], cfg), stacks,
+                         layer)
     return x + y, kv, aux
 
 
@@ -168,13 +192,19 @@ def group_forward(params: Any, cfg: ModelConfig, spec: GroupSpec, x: jax.Array,
         return x, 0.0, (kvs if want_cache else None)
 
     if spec.kind == "moe":
-        def body(carry, lp):
+        params, stacks = _held_stacks(params, cfg, spec)
+
+        def body(carry, inp):
             h, aux = carry
-            h2, kv, a = _moe_block_full(lp, cfg, h, positions, mask)
+            lp, i = inp
+            h2, kv, a = _moe_block_full(lp, cfg, h, positions, mask, stacks,
+                                        i)
             return (h2, aux + a), (kv if want_cache else 0)
         body = jax.checkpoint(body) if cfg.remat else body
-        (x, aux), kvs = jax.lax.scan(body, (x, jnp.float32(0.0)), params,
-                                     unroll=cfg.scan_unroll)
+        (x, aux), kvs = jax.lax.scan(
+            body, (x, jnp.float32(0.0)),
+            (params, jnp.arange(spec.count) if stacks else None),
+            unroll=cfg.scan_unroll)
         return x, aux, (kvs if want_cache else None)
 
     if spec.kind in ("mamba1", "mamba2"):
@@ -220,26 +250,24 @@ def _moe_block_decode(p, cfg, x, positions, cache, slot, mask):
     return x + y, c2
 
 
-def _dense_block_decode_paged(p, cfg, x, positions, pool, page_table,
-                              write_page, write_off, mask, attn_fn=None):
-    attn_fn = attn_fn or attention.attn_decode_paged
+def _block_paged(p, cfg, x, positions, pool, page_table, write_page,
+                 write_off, mask, attn_fn, stacks=None, layer=None):
+    """One attention block against the page pool, its FFN dense or MoE.
+    Returns (x, new pool, counts): the dropless MoE share's counters
+    (``moe.moe_held``) over the tokens someone is served, those that
+    write into a page of their own (idle rows and pad entries write into
+    the trash page); no leaves for any other FFN."""
     h, c2 = attn_fn(
         p["attn"], cfg, apply_norm(x, p["norm1"], cfg), positions, pool,
         page_table, write_page, write_off, mask)
     x = x + h
-    x = x + moe_lib.dense_mlp(p["mlp"], cfg, apply_norm(x, p["norm2"], cfg))
-    return x, c2
-
-
-def _moe_block_decode_paged(p, cfg, x, positions, pool, page_table,
-                            write_page, write_off, mask, attn_fn=None):
-    attn_fn = attn_fn or attention.attn_decode_paged
-    h, c2 = attn_fn(
-        p["attn"], cfg, apply_norm(x, p["norm1"], cfg), positions, pool,
-        page_table, write_page, write_off, mask)
-    x = x + h
-    y, _ = moe_lib.moe_mlp(p["moe"], cfg, apply_norm(x, p["norm2"], cfg))
-    return x + y, c2
+    hn = apply_norm(x, p["norm2"], cfg)
+    if "mlp" in p:
+        return x + moe_lib.dense_mlp(p["mlp"], cfg, hn), c2, {}
+    from repro.core.paging import TRASH_PAGE
+    live = (write_page != TRASH_PAGE).reshape(x.shape[:2])
+    y, _, counts = _moe_ffn(p, cfg, hn, stacks, layer, live)
+    return x + y, c2, counts
 
 
 def _mamba_block_decode(p, cfg, x, state):
@@ -291,6 +319,28 @@ def group_decode(params: Any, cfg: ModelConfig, spec: GroupSpec, x: jax.Array,
     raise ValueError(spec.kind)
 
 
+def _group_paged(params: Any, cfg: ModelConfig, spec: GroupSpec,
+                 x: jax.Array, positions: jax.Array, pool: Any,
+                 page_table: jax.Array, write_page: jax.Array,
+                 write_off: jax.Array, mask: jax.Array, attn_fn):
+    if spec.kind not in ("dense", "moe"):
+        raise NotImplementedError(
+            f"the paged pool covers attention stacks only, not {spec.kind}")
+    params, stacks = _held_stacks(params, cfg, spec)
+
+    def body(h, inp):
+        lp, c, i = inp
+        h, c, counts = _block_paged(lp, cfg, h, positions, c, page_table,
+                                    write_page, write_off, mask, attn_fn,
+                                    stacks, i)
+        return h, (c, counts)
+
+    x, (pool, counts) = jax.lax.scan(
+        body, x, (params, pool, jnp.arange(spec.count) if stacks else None),
+        unroll=cfg.scan_unroll)
+    return x, pool, jax.tree.map(lambda a: jnp.sum(a, axis=0), counts)
+
+
 def group_decode_paged(params: Any, cfg: ModelConfig, spec: GroupSpec,
                        x: jax.Array, positions: jax.Array, pool: Any,
                        page_table: jax.Array, write_page: jax.Array,
@@ -298,27 +348,11 @@ def group_decode_paged(params: Any, cfg: ModelConfig, spec: GroupSpec,
     """Single-token decode through one group against a shared KV page
     pool (leaves (L, P, page, ...)). Attention-cache stacks only — SSM
     recurrent state has no sequence axis to page. Returns
-    (x, new pool)."""
-    if spec.kind == "dense":
-        def body(h, inp):
-            lp, c = inp
-            return _dense_block_decode_paged(lp, cfg, h, positions, c,
-                                             page_table, write_page,
-                                             write_off, mask)
-        return jax.lax.scan(body, x, (params, pool),
-                            unroll=cfg.scan_unroll)
-
-    if spec.kind == "moe":
-        def body(h, inp):
-            lp, c = inp
-            return _moe_block_decode_paged(lp, cfg, h, positions, c,
-                                           page_table, write_page,
-                                           write_off, mask)
-        return jax.lax.scan(body, x, (params, pool),
-                            unroll=cfg.scan_unroll)
-
-    raise NotImplementedError(
-        f"paged decode caches cover attention stacks only, not {spec.kind}")
+    (x, new pool, counts): the group's dropless-MoE counters summed over
+    its layers (``_block_paged``), an empty dict for other groups."""
+    return _group_paged(params, cfg, spec, x, positions, pool, page_table,
+                        write_page, write_off, mask,
+                        attention.attn_decode_paged)
 
 
 def group_verify_paged(params: Any, cfg: ModelConfig, spec: GroupSpec,
@@ -329,27 +363,10 @@ def group_verify_paged(params: Any, cfg: ModelConfig, spec: GroupSpec,
     the shared KV page pool: x (B, C, d) chunk tokens, positions /
     write_page / write_off (B, C), mask (B, C, n_pages*page). Same layer
     scan as ``group_decode_paged`` with the multi-query attention body.
-    Returns (x, new pool)."""
-    if spec.kind == "dense":
-        def body(h, inp):
-            lp, c = inp
-            return _dense_block_decode_paged(
-                lp, cfg, h, positions, c, page_table, write_page,
-                write_off, mask, attn_fn=attention.attn_verify_paged)
-        return jax.lax.scan(body, x, (params, pool),
-                            unroll=cfg.scan_unroll)
-
-    if spec.kind == "moe":
-        def body(h, inp):
-            lp, c = inp
-            return _moe_block_decode_paged(
-                lp, cfg, h, positions, c, page_table, write_page,
-                write_off, mask, attn_fn=attention.attn_verify_paged)
-        return jax.lax.scan(body, x, (params, pool),
-                            unroll=cfg.scan_unroll)
-
-    raise NotImplementedError(
-        f"paged verify covers attention stacks only, not {spec.kind}")
+    Returns (x, new pool, counts)."""
+    return _group_paged(params, cfg, spec, x, positions, pool, page_table,
+                        write_page, write_off, mask,
+                        attention.attn_verify_paged)
 
 
 # ---------------------------------------------------------------------------
@@ -393,5 +410,5 @@ def count_params(cfg: ModelConfig, active_only: bool = False) -> int:
         m = cfg.moe
         per_expert = (3 if cfg.gated_mlp else 2) * cfg.d_model * m.d_ff_expert
         n_moe_layers = cfg.num_layers - m.first_k_dense
-        total -= n_moe_layers * (m.num_experts - m.top_k) * per_expert
+        total -= n_moe_layers * max(0, m.held - m.top_k) * per_expert
     return total

@@ -97,6 +97,7 @@ class ShardedServingContext:
         self.param_shardings = sh.to_shardings(mesh, pspecs)
         self.params = jax.device_put(executor.params, self.param_shardings)
         self._stages: Dict[Any, Callable] = {}
+        self.step_counts: Dict = {}     # as DualStreamExecutor's
 
     def __getattr__(self, name: str) -> Any:
         if name == "inner":
@@ -190,13 +191,14 @@ class ShardedServingContext:
             "cloud_decode_rows", fn,
             lambda args: (self.param_shardings, self._kv_sh(args[1]))
             + (self._rep,) * 5,
-            lambda outs: (self._rep, self._rep, self._kv_sh(outs[2])))
-        return stage(self.params, pool,
-                     jnp.asarray(page_table, jnp.int32),
-                     jnp.asarray(positions, jnp.int32),
-                     jnp.asarray(tokens, jnp.int32),
-                     jnp.asarray(pos, jnp.int32),
-                     jnp.asarray(write_slot, jnp.int32))
+            lambda outs: (self._rep, self._rep, self._kv_sh(outs[2]),
+                          self._rep))
+        logits, seg, pool, self.step_counts = stage(
+            self.params, pool, jnp.asarray(page_table, jnp.int32),
+            jnp.asarray(positions, jnp.int32),
+            jnp.asarray(tokens, jnp.int32), jnp.asarray(pos, jnp.int32),
+            jnp.asarray(write_slot, jnp.int32))
+        return logits, seg, pool
 
     def cloud_verify_rows(self, pool: Dict, page_table, positions, tokens,
                           pos, write_slot, chunk_len
@@ -211,14 +213,15 @@ class ShardedServingContext:
             "cloud_verify_rows", fn,
             lambda args: (self.param_shardings, self._kv_sh(args[1]))
             + (self._rep,) * 6,
-            lambda outs: (self._rep, self._rep, self._kv_sh(outs[2])))
-        return stage(self.params, pool,
-                     jnp.asarray(page_table, jnp.int32),
-                     jnp.asarray(positions, jnp.int32),
-                     jnp.asarray(tokens, jnp.int32),
-                     jnp.asarray(pos, jnp.int32),
-                     jnp.asarray(write_slot, jnp.int32),
-                     jnp.asarray(chunk_len, jnp.int32))
+            lambda outs: (self._rep, self._rep, self._kv_sh(outs[2]),
+                          self._rep))
+        logits, seg, pool, self.step_counts = stage(
+            self.params, pool, jnp.asarray(page_table, jnp.int32),
+            jnp.asarray(positions, jnp.int32),
+            jnp.asarray(tokens, jnp.int32), jnp.asarray(pos, jnp.int32),
+            jnp.asarray(write_slot, jnp.int32),
+            jnp.asarray(chunk_len, jnp.int32))
+        return logits, seg, pool
 
     # ---- the Context draft stages (DraftModel's fns_factory hook) ----
 

@@ -121,7 +121,7 @@ def test_paged_decode_matches_llm_generate(flash):
         tk = np.full((B, 1), toks[-1], np.int32)
         pos = np.full((B,), S + t, np.int32)
         ws = np.full((B,), base + t, np.int32)
-        logits, seg, pool = vlm.llm_decode_step_paged(
+        logits, seg, pool, _ = vlm.llm_decode_step_paged(
             params, pcfg, pool, pt, positions, tk, pos, ws)
         positions[:, base + t] = S + t
         if t < T - 1:
@@ -185,7 +185,7 @@ def test_verify_step_matches_sequential_decode_steps(flash):
     seq_logits, seq_seg = [], []
     for t in range(T):
         tk = np.full((B, 1), toks[-1], np.int32)
-        lg, sg, pool = vlm.llm_decode_step_paged(
+        lg, sg, pool, _ = vlm.llm_decode_step_paged(
             params, pcfg, pool, pt, pos_seq, tk,
             np.full((B,), S + t, np.int32), np.full((B,), base + t,
                                                     np.int32))
@@ -197,7 +197,7 @@ def test_verify_step_matches_sequential_decode_steps(flash):
     # one verify chunk: row 0 carries all T tokens, row 1 only 2 (padded)
     chunk = np.tile(np.asarray(toks[:T], np.int32), (B, 1))
     clens = np.asarray([T, 2], np.int32)
-    lgv, segv, _ = vlm.llm_verify_step_paged(
+    lgv, segv, _, _ = vlm.llm_verify_step_paged(
         params, pcfg, fresh_pool(), pt, positions, chunk,
         np.full((B,), S, np.int32), np.full((B,), base, np.int32), clens)
     lgv, segv = np.asarray(lgv), np.asarray(segv)

@@ -9,6 +9,7 @@ refuses what Mosaic would refuse on the chip (block shapes off the
 tiling, VMEM overuse). The topology is described inside a module-scoped
 fixture only -- never at import -- so every pytest worker collects the
 same tests and only the worker that runs them loads the TPU library."""
+import dataclasses
 import functools
 import importlib
 
@@ -107,3 +108,45 @@ def test_paged_decode_compiles_for_v5e_at_phi4mini_widths(one_chip, pages,
                         pool, pool, ((slots, pages), i32),
                         ((slots, pages * PAGE), f32))
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_latent_expert_share_decode_compiles_for_v5e_at_deepseek_v3_widths(
+        one_chip):
+    """Two MoE layers of DeepSeek-V3's widths against a latent page pool
+    (8 slots, 16 pages a row, 255 pages): latent attention, 256-way
+    group-limited routing and 8 held experts of 2048. A held expert is
+    sliced by (layer, expert) inside its own branch, so no program
+    copies a layer's whole stack of experts (704 MB) before the
+    branches run."""
+    from repro.configs import get_config
+    from repro.models import stack
+    dsv3 = get_config("deepseek-v3-671b")
+    llm = dsv3.replace(num_layers=2, moe=dataclasses.replace(
+        dsv3.moe, first_k_dense=0, dispatch="dropless", held_experts=8))
+    spec = stack.layer_groups(llm)[0]
+    slots, pages, pool_pages = 8, 16, 255
+    m = llm.mla
+    params = jax.eval_shape(lambda: stack.init_group(
+        jax.random.PRNGKey(0), llm, spec))
+    bf, f32, i32 = jnp.bfloat16, jnp.float32, jnp.int32
+    pool = {"ckv": jax.ShapeDtypeStruct(
+                (2, pool_pages, PAGE, m.kv_lora_rank), bf),
+            "krope": jax.ShapeDtypeStruct(
+                (2, pool_pages, PAGE, m.qk_rope_head_dim), bf)}
+
+    def step(p, pool, x, pos, pt, wp, wo, mask):
+        return stack.group_decode_paged(p, llm, spec, x, pos, pool, pt, wp,
+                                        wo, mask)
+
+    shard = lambda t: jax.tree.map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        t)
+    args = [shard(params), shard(pool)] + [
+        jax.ShapeDtypeStruct(s, dt, sharding=one_chip) for s, dt in [
+            ((slots, 1, llm.d_model), bf), ((slots, 1), i32),
+            ((slots, pages), i32), ((slots,), i32), ((slots,), i32),
+            ((slots, pages * PAGE), f32)]]
+    compiled = jax.jit(step).lower(*args).compile()
+    expert_stack = 3 * 8 * llm.d_model * llm.moe.d_ff_expert * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < expert_stack / 4
+    assert " conditional(" in compiled.as_text()
