@@ -26,7 +26,7 @@ real in-flight engine (CI's averylint step).
 from __future__ import annotations
 
 import contextlib
-from typing import Any, Dict, Iterable, List
+from typing import Any, Dict, Iterable, List, Tuple
 
 
 class RecompileBudgetError(AssertionError):
@@ -92,24 +92,41 @@ def jit_roots(engine: Any) -> List[Any]:
 
 
 class RecompileSanitizer:
-    """Counts distinct compiled traces across the engine's jit roots."""
+    """Counts distinct compiled traces across the engine's jit roots.
+
+    Once armed it keeps every root it has seen, with the count it had
+    when first seen: a retired decoder's jits stay counted, and a jit
+    that later decoders share (the greedy pick, the draft stages) is not
+    new again each time a fresh decoder brings it back into reach."""
 
     def __init__(self, engine: Any):
         self.engine = engine
         self.armed_at: "int | None" = None
+        self._base: Dict[int, Tuple[Any, int]] = {}   # id -> (jit, count)
 
     def compile_count(self) -> int:
         return sum(int(f._cache_size()) for f in jit_roots(self.engine))
 
     def arm(self) -> int:
         """Snapshot after warmup; subsequent compiles are violations."""
+        self._base = {}
+        self.adopt()
         self.armed_at = self.compile_count()
         return self.armed_at
+
+    def adopt(self) -> None:
+        """Take roots not seen yet at their present count. The engine
+        calls this when a decoder comes into reach, before it runs."""
+        for f in jit_roots(self.engine):
+            self._base.setdefault(id(f), (f, int(f._cache_size())))
 
     def new_compiles(self) -> int:
         if self.armed_at is None:
             return 0
-        return self.compile_count() - self.armed_at
+        # a root first seen here has run unseen: all its traces are new
+        for f in jit_roots(self.engine):
+            self._base.setdefault(id(f), (f, 0))
+        return sum(int(f._cache_size()) - n for f, n in self._base.values())
 
     def check(self, budget: int = 0) -> None:
         n = self.new_compiles()

@@ -32,8 +32,9 @@ SPANS = (
     "inflight.step",          # one decode or verify step
     "inflight.step.inputs",   # token, position, write-slot arrays
     "inflight.step.launch",   # the cloud_decode_rows / cloud_verify_rows call
-    "inflight.step.fetch",    # logits and seg to the host
-    "inflight.step.sample",   # argmax, positions, per-row bookkeeping
+    "inflight.step.fetch",    # greedy pick on the device; ids, counters
+                              # (and seg if a row may finish) to the host
+    "inflight.step.sample",   # ids into tokens, positions, bookkeeping
     "inflight.finish",        # mask, on_done and resolve; arg rid
     "inflight.draft",         # draft admit and draft steps
 )

@@ -130,9 +130,9 @@ class DualStreamExecutor:
         # same program as its logits: the routed-expert counts of a trunk
         # with a dropless MoE share (``vlm.llm_decode_step_paged``), no
         # leaves otherwise. The in-flight decoder fetches them with the
-        # logits of that step, before it launches the next; they sit here
-        # and not in the stage's return, which every wrapper of the stage
-        # takes as (logits, seg, pool)
+        # greedy ids of that step, before it launches the next; they sit
+        # here and not in the stage's return, which every wrapper of the
+        # stage takes as (logits, seg, pool)
         self.step_counts: Dict = {}
 
     # ---- compile cache ----

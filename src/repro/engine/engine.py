@@ -772,6 +772,9 @@ class AveryEngine:
                     tracer=self.tracer, metrics=self.metrics,
                     wallclock=self._wallclock,
                     profiler=self.profiler, cost=self.cost_model)
+                san = self._recompile_sanitizer
+                if san is not None and san.armed_at is not None:
+                    san.adopt()   # its shared jits' traces are not new
             dec.submit(rid, fut.request.intent, packet, query,
                        on_done=self._resolve_inflight,
                        operator_id=fut.request.operator_id,

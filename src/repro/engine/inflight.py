@@ -57,18 +57,20 @@ accepted only where it equals the serving model's own greedy pick.
 """
 from __future__ import annotations
 
+import functools
 from collections import deque
 from dataclasses import dataclass, fields
 from typing import Any, Callable, Deque, Dict, List, Optional, Tuple
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.core import packets as pk
 from repro.core.intent import Intent
 from repro.core.paging import (TRASH_PAGE, PagePool, pages_for,
                                prefix_digest, prefix_positions)
-from repro.core.spans import span
+from repro.core.spans import named_stage, span
 from repro.engine.faults import CloudStageError
 from repro.engine.observability import Tracer
 from repro.engine.scheduler import FifoScheduler, qos_class
@@ -94,6 +96,9 @@ class DecodeTotals:
     # held experts, and held (layer, expert) pairs with at least one pick
     expert_picks: int = 0
     experts_hit: int = 0
+    # bytes the steps copied back to the host: greedy ids, counters, and
+    # the <SEG> states of a step on which a row may finish
+    fetched_bytes: int = 0
 
     def add_counts(self, counts: Dict[str, Any]) -> None:
         """Fold one step's device counters (named as the fields) in."""
@@ -108,6 +113,21 @@ class DecodeTotals:
     def as_dict(self) -> Dict[str, int]:
         return {f"inflight_{f.name}": getattr(self, f.name)
                 for f in fields(self)}
+
+
+def greedy_pick(logits):
+    """The greedy token of each row over the last axis: ``(slots, V)``
+    logits give ``(slots,)`` ids, a verify step's ``(slots, C, V)`` give
+    ``(slots, C)``. Ties go to the first maximum, as ``np.argmax``."""
+    return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+
+@functools.lru_cache(maxsize=None)
+def _greedy_pick_jit():
+    """One jitted pick for every decoder: decoders retire on
+    ``engine.drain()``, and a fresh ``jax.jit`` each would recompile it
+    on every burst (as ``speculative._draft_fns``)."""
+    return jax.jit(named_stage("greedy_pick", greedy_pick))
 
 
 @dataclass
@@ -174,6 +194,10 @@ class InflightDecoder:
                  profiler: Optional[Any] = None,
                  cost: Optional[Any] = None):
         self.executor = executor
+        # the step's greedy pick, run on the logits the stage returned so
+        # only ids come back to the host; an attribute, so the recompile
+        # census (``analysis.sanitizers``) walks it with the decoder
+        self.greedy_pick = _greedy_pick_jit()
         # device-level observability (engine.profiler): the profiler
         # wraps lazily built draft models; the cost model attributes
         # analytic FLOPs/HBM bytes to each request as it decodes
@@ -580,7 +604,9 @@ class InflightDecoder:
         except CloudStageError as e:
             return self._fail_step(e)
         with span("inflight.step.fetch"):
-            logits, seg = self._fetch(logits, seg)
+            # only the final step of a row reads its <SEG> state
+            ids, seg = self._fetch(logits, seg, finishing=any(
+                len(st.tokens) >= self.T for st in self.active.values()))
         # the step as the host sees it: launch, device and the copy back
         if wc is not None and self._metrics is not None:
             self._metrics.histogram("decode_step_s").observe(wc() - w0)
@@ -611,7 +637,7 @@ class InflightDecoder:
                         st.tokens.append(st.replay.popleft())
                         self.scheduler.note_replayed()
                     else:
-                        st.tokens.append(int(np.argmax(logits[s])))
+                        st.tokens.append(int(ids[s]))
                     st.pos += 1
                     continue
                 # final step: this row's seg is the <SEG> state at the
@@ -668,7 +694,10 @@ class InflightDecoder:
         except CloudStageError as e:
             return self._fail_step(e)
         with span("inflight.step.fetch"):
-            logits, seg = self._fetch(logits, seg)
+            # a row can finish only if its chunk reaches the last token
+            ids, seg = self._fetch(logits, seg, finishing=any(
+                len(st.tokens) + n_drafted.get(s, 0) >= self.T
+                for s, st in self.active.items()))
         if wc is not None and self._metrics is not None:
             self._metrics.histogram("verify_step_s").observe(wc() - w0)
         live = len(self.active)
@@ -695,7 +724,7 @@ class InflightDecoder:
                             st.pos + i + 1)
                 # greedy[i]: the serving model's own pick after chunk
                 # token i
-                greedy = np.argmax(logits[s, :1 + j], axis=-1)
+                greedy = ids[s, :1 + j]
                 m = greedy_accept(toks[s, 1:1 + j], greedy) if j else 0
                 # chunk tokens 0..m are now committed: the real last
                 # token plus m accepted drafts
@@ -745,15 +774,21 @@ class InflightDecoder:
             self.admit()
         return finished
 
-    def _fetch(self, logits, seg):
-        """A step's logits and <SEG> states on the host. The device
-        counters the same program left on the executor (``step_counts``;
-        no other stage call comes between the launch and this fetch)
-        come back in the same copy and go into the totals."""
-        logits, seg, counts = jax.device_get(
-            (logits, seg, getattr(self.executor, "step_counts", {})))
+    def _fetch(self, logits, seg, finishing: bool):
+        """A step's greedy ids on the host, picked on the device from the
+        logits the stage returned, and its <SEG> states when ``finishing``
+        (else None). The device counters the same program left on the
+        executor (``step_counts``; no other stage call comes between the
+        launch and this fetch) come back in the same copy and go into the
+        totals."""
+        got = jax.device_get((self.greedy_pick(logits),
+                              seg if finishing else None,
+                              getattr(self.executor, "step_counts", {})))
+        self.totals.fetched_bytes += sum(
+            np.asarray(x).nbytes for x in jax.tree_util.tree_leaves(got))
+        ids, seg, counts = got
         self.totals.add_counts(counts)
-        return logits, seg
+        return ids, seg
 
     def _grow_private(self, slot: int, st: _SlotState, tokens: int) -> None:
         """Extend one row's private decode pages to cover ``tokens``

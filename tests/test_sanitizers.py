@@ -105,6 +105,28 @@ def test_jit_roots_discovery(cold_executor):
     assert san.compile_count() > before     # first traffic compiles
 
 
+def test_census_counts_the_decoders_greedy_pick(executor):
+    """A live decoder's jitted greedy pick is a census root, and it
+    stays counted after a drain retires the decoder: compiles of it
+    after arm are new, a later decoder bringing it back is not."""
+    from repro.analysis.sanitizers import named_jit_roots
+    engine = _engine(executor, debug_recompiles=True)
+    _submit(engine, executor, 2, 0, 0.0)
+    (qlen, dec), = engine._inflight.items()
+    roots = named_jit_roots(engine)
+    assert roots[f"decoder[{qlen}].greedy_pick"] is dec.greedy_pick
+    engine.drain()
+    assert not engine._inflight
+    engine.arm_sanitizers()
+    _submit(engine, executor, 2, 1, 1.0)
+    engine.drain()
+    assert engine.stats["new_compiles_since_arm"] == 0
+    # a logits shape no step has had compiles one more trace of the pick
+    import jax.numpy as jnp
+    dec.greedy_pick(jnp.zeros((3, 5), jnp.float32))
+    assert engine.stats["new_compiles_since_arm"] == 1
+
+
 def test_sanitizer_stats_and_noop_paths():
     """Host-only engine: sanitizer knobs are inert but well-formed."""
     class StubExecutor:

@@ -10,6 +10,7 @@ import pytest
 from repro.core import spans
 from repro.core.intent import Intent
 from repro.engine import AveryEngine
+from repro.engine import inflight as inflight_mod
 from repro.engine import speculative as spec_mod
 from repro.engine.speculative import SpeculativeConfig
 
@@ -21,6 +22,7 @@ PLAIN = ("edge_context", "edge_insight", "bottleneck_encode",
          "cloud_context", "cloud_insight", "cloud_context_gen",
          "cloud_insight_gen")
 DRAFT = ("draft_prefill", "draft_step", "draft_insert")
+DECODER = ("greedy_pick",)
 SHARDED = ("cloud_prefix", "pool_write", "cloud_decode_rows",
            "cloud_verify_rows") + DRAFT
 STAGES = ("cloud_prefix", "pool_write", "cloud_decode_rows",
@@ -75,6 +77,7 @@ def jitted(system):
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(jax, "jit", recording_jit)
         spec_mod._draft_fns.cache_clear()   # draft jits built afresh
+        inflight_mod._greedy_pick_jit.cache_clear()   # and the pick
         ex = _executor(system)
         reqs = _edge_requests(ex, 3, seed=4)
         ctx_req, ins_req = reqs[2], reqs[0]
@@ -90,11 +93,12 @@ def jitted(system):
                             shards=sh.model_shards)
             _serve(InflightDecoder(sh, slots=2, pool=pool, spec=s), reqs)
     spec_mod._draft_fns.cache_clear()
+    inflight_mod._greedy_pick_jit.cache_clear()
     return seen
 
 
 @pytest.mark.parametrize("family,stage",
-                         [("plain", s) for s in PLAIN + DRAFT]
+                         [("plain", s) for s in PLAIN + DRAFT + DECODER]
                          + [("sharded", s) for s in SHARDED])
 def test_stage_lowers_to_its_named_module(jitted, family, stage):
     jit, args = jitted[(family, stage)]
@@ -241,7 +245,8 @@ def test_submit_and_admit_carry_the_request_id(served):
 
 
 class _SlowFetch:
-    """Logits whose copy to the host advances the fake wall clock."""
+    """A step output whose copy to the host advances the fake wall
+    clock."""
 
     def __init__(self, arr, clock):
         self.arr, self.clock = arr, clock
@@ -258,13 +263,11 @@ class _SlowFetchExecutor:
     def __getattr__(self, name):
         return getattr(self._inner, name)
 
-    def cloud_decode_rows(self, *args):
-        logits, seg, pool = self._inner.cloud_decode_rows(*args)
-        return _SlowFetch(logits, self.clock), seg, pool
-
-    def cloud_verify_rows(self, *args):
-        logits, seg, pool = self._inner.cloud_verify_rows(*args)
-        return _SlowFetch(logits, self.clock), seg, pool
+    @property
+    def step_counts(self):
+        # the step's device counters ride the same copy as its greedy
+        # ids (the logits stay on the device)
+        return {"expert_picks": _SlowFetch(np.int32(0), self.clock)}
 
 
 @pytest.mark.parametrize("mode,histogram", [("plain", "decode_step_s"),
