@@ -498,77 +498,6 @@ def chaos_rows(executor, n_uavs=CHAOS_UAVS, frames=CHAOS_FRAMES,
         f"uavs={n_uavs};frames_per_uav={frames}")]
 
 
-def profiled_rows(executor, n_uavs=2, frames=3, emit_row=None,
-                  artifact_tag="profiled"):
-    """Device-level observability mode (docs/observability.md
-    §Profiler): the repeat-prefix fleet burst served through the
-    in-flight engine bare and again with the ``StageProfiler`` on.
-    Reports the profiler's measured overhead against its <5% budget,
-    the compile observatory's event count, and the cost/energy ledger
-    totals. The run *asserts* the observability contract — profiling
-    changes no served token, the Perfetto artifact gains a validating
-    device track, and every served response carries a positive FLOPs/
-    energy ledger — so CI cannot record a green row for a profiler
-    that perturbs or under-reports the engine."""
-    import time as _time
-
-    from repro.engine.observability import DEVICE_TRACK_PID
-
-    emit_row = emit_row or emit
-    reqs = _uav_stream(executor, n_uavs, frames, "insight")
-    out = {}
-
-    def serve(profile):
-        engine = make_engine(
-            executor, batching="inflight", max_batch=8, trace=profile,
-            profile=profile,
-            wallclock=_time.perf_counter if profile else None)
-        futs = [engine.submit_packet(pkt, q, Intent.INSIGHT,
-                                     time_s=float(i))
-                for i, (_, pkt, q) in enumerate(reqs)]
-        engine.drain()
-        out[profile] = (engine, [f.result() for f in futs])
-
-    t_bare = time_best(lambda: serve(False))
-    t_prof = time_best(lambda: serve(True))
-    engine, resps = out[True]
-    bare_resps = out[False][1]
-    for a, b in zip(bare_resps, resps):
-        if not np.array_equal(a.tokens, b.tokens):
-            raise AssertionError(
-                f"profiling changed request {b.request_id}'s tokens")
-    for r in resps:
-        if r.failure is None and not (r.cloud_flops and r.cloud_flops > 0
-                                      and r.cloud_energy_j
-                                      and r.cloud_energy_j > 0):
-            raise AssertionError(
-                f"served request {r.request_id} has an empty cost "
-                f"ledger (flops={r.cloud_flops})")
-    path = _dump_trace_artifact(engine, artifact_tag)
-    with open(path) as f:
-        doc = json.load(f)
-    dev = [e for e in doc["traceEvents"]
-           if e.get("pid") == DEVICE_TRACK_PID and e.get("ph") == "X"]
-    if not dev:
-        raise AssertionError(
-            f"profiled trace artifact {path} has no device track "
-            f"(pid {DEVICE_TRACK_PID})")
-    st = engine.stats
-    if st["profiled_stage_calls"] <= 0:
-        raise AssertionError("profiler recorded no stage calls")
-    return [emit_row(
-        "serving/profiled", t_prof * 1e6,
-        f"req_s={len(reqs) / t_prof:.1f};"
-        f"profile_overhead={t_prof / t_bare:.3f}x;"
-        f"profiled_stage_calls={int(st['profiled_stage_calls'])};"
-        f"compile_events={int(st['compile_events'])};"
-        f"ledger_flops_total={st['ledger_flops_total']:.3g};"
-        f"ledger_energy_j_total={st['ledger_energy_j_total']:.3g};"
-        f"decode_roofline_frac={st['decode_roofline_frac']:.3g};"
-        f"device_events={len(dev)};"
-        f"uavs={n_uavs};frames_per_uav={frames}")]
-
-
 def _storm_ops(duration_s):
     """The storm's operator roster: (op, kind, priority, t_start, t_end,
     mean-gap scale). Two recon streams are the latency class, the
@@ -941,20 +870,6 @@ def run_chaos_smoke():
     return rows
 
 
-def run_profiled_smoke():
-    """CI smoke: the device-level observability mode at a reduced size
-    (2 UAVs x 3 frames) — StageProfiler wrap, compile observatory,
-    cost/energy ledger, and the Perfetto device track end to end in
-    seconds, with the same hard asserts (token-exact under profiling,
-    validating device track, positive per-request ledger) as the full
-    run."""
-    rows = profiled_rows(_smoke_executor(), n_uavs=2, frames=3,
-                         emit_row=_smoke_emit,
-                         artifact_tag="profiled_smoke")
-    write_bench_json(rows)
-    return rows
-
-
 def run_fleet_storm():
     """Fleet storm mode on its own: the full-size multi-tenant trace
     (7 operators, 40 mission seconds) under FIFO vs QoS scheduling,
@@ -999,8 +914,6 @@ if __name__ == "__main__":
         run_sharded_smoke()
     elif "--sharded" in sys.argv:
         run_sharded()
-    elif "--profiled-smoke" in sys.argv:
-        run_profiled_smoke()
     elif "--chaos-smoke" in sys.argv:
         run_chaos_smoke()
     elif "--chaos" in sys.argv:

@@ -137,37 +137,24 @@ def emit(name: str, us: float, derived: str) -> str:
 
 
 def write_bench_json(rows, filename: str = "BENCH_serving.json") -> str:
-    """Persist benchmark rows as a machine-readable artifact so the perf
-    trajectory is tracked across PRs instead of living only in logs.
+    """Persist benchmark rows as a machine-readable artifact.
 
     ``rows`` are the strings ``emit`` returns (``name,us,k=v;k=v;...``);
     they merge by row name into ``benchmarks/artifacts/<filename>``, so
     partial runs (``--paged-smoke``, ``--spec``, ``--sharded``) update
-    their rows without clobbering the rest. The merged artifact is also
-    mirrored to the repo root, where the cross-PR perf trajectory is
-    tracked (a committed file, not just a benchmark byproduct).
-    Returns the artifact path."""
+    their rows without clobbering the rest. Nothing outside the
+    artifacts directory is written. Returns the artifact path."""
     import json
-    import shutil
 
     path = os.path.join(ART, filename)
-    mirror = os.path.abspath(os.path.join(ART, os.pardir, os.pardir,
-                                          filename))
     records = {}
-    # merge base: the local artifact, else the committed root mirror —
-    # a fresh checkout inherits the tracked trajectory instead of
-    # clobbering it down to whichever partial mode ran first (the perf
-    # gate treats a vanished row as a regression, by design)
-    for prev_path in (path, mirror):
-        if not os.path.exists(prev_path):
-            continue
+    if os.path.exists(path):
         try:
-            with open(prev_path) as f:
+            with open(path) as f:
                 prev = json.load(f)
             if isinstance(prev, dict) and isinstance(prev.get("records"),
                                                      dict):
                 records = prev["records"]
-                break
         except (json.JSONDecodeError, OSError):
             pass                       # corrupt artifact: regenerate
     for row in rows:
@@ -187,5 +174,4 @@ def write_bench_json(rows, filename: str = "BENCH_serving.json") -> str:
         json.dump({"benchmark": os.path.splitext(filename)[0],
                    "records": records}, f, indent=2, sort_keys=True)
         f.write("\n")
-    shutil.copyfile(path, mirror)
     return path

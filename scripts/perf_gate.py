@@ -1,33 +1,28 @@
 #!/usr/bin/env python
-"""Continuous perf-regression gate over ``BENCH_serving.json``.
+"""Perf-regression gate over the serving bench's counts.
 
-The serving bench writes one JSON artifact per run (merged rows, root
-mirror — see ``benchmarks/common.write_bench_json``); this script diffs
-it against the committed ``BENCH_baseline.json`` with per-metric
-direction + tolerance budgets and exits 1 on any regression, so the
-repo's perf trajectory is *gated*, not write-only. Every future perf
-item on the ROADMAP (shard_map kernels, quantized KV, disaggregated
-prefill/decode) lands against this gate.
+The serving bench writes one JSON artifact per run,
+``benchmarks/artifacts/BENCH_serving.json`` (rows merged by name — see
+``benchmarks/common.write_bench_json``); this script diffs it against
+the committed ``BENCH_baseline.json`` with per-metric direction +
+tolerance budgets and exits 1 on any regression.
+
+The bench runs on the CPU, which can show counts, correctness and
+mission-clock latencies, not speed: those are deterministic under the
+rows' seeds. Wall-clock metrics are never gated; device speed is the
+chip benchmark's (``perfbench/``).
 
 Metric classification (``classify``):
 
-  * **lower-better** — wall timings: ``us`` and any ``*_s``/``*_us``
-    metric, plus ``profile_overhead``. Regression when the new value
-    exceeds baseline by more than ``--tolerance`` (relative).
-  * **higher-better** — quality/throughput: ``req_s``-family rates,
-    SLO/hit/acceptance rates, Jain fairness, tokens/step, saved
-    FLOPs/bytes. Regression when the new value falls below baseline by
-    more than ``--quality-tolerance`` (relative); ``speedup_*`` ratios
-    are timing-derived, so they use the (looser) time tolerance on the
-    same lower bound — BUT a speedup is self-normalized (numerator and
-    denominator are measured in the same run, so machine load largely
-    cancels), so any speedup whose baseline claims a material win
-    (>= ``SPEEDUP_PARITY_MARGIN``) additionally gates hard at the
-    parity floor: a recorded value below 1.0 means the accelerated
-    path measured *slower* than its own in-run baseline, which no
-    tolerance excuses. Near-parity baselines (e.g. the CPU-container
-    spec-decode row, whose draft shares the target's geometry) stay
-    on the relative budget only, so they cannot flap CI.
+  * **ignored: wall clock** — ``us``, ``req_s`` and ``*_req_s``,
+    ``tok_s`` and ``*_tok_s``, ``speedup_*``, ``vs_unsharded``.
+  * **lower-better** — mission-clock latencies: any ``*_s``/``*_us``
+    metric. Regression when the new value exceeds baseline by more
+    than ``--tolerance`` (relative).
+  * **higher-better** — quality and counts: SLO/hit/acceptance rates,
+    Jain fairness, tokens/step, saved FLOPs/bytes. Regression when the
+    new value falls below baseline by more than ``--quality-tolerance``
+    (relative).
   * **zero-tolerance** — ``page_leaks``: any nonzero value is a
     regression regardless of baseline or tolerance.
   * **ignored** — run geometry (seeds, sizes, SLOs), fault-schedule
@@ -36,49 +31,35 @@ Metric classification (``classify``):
 
 A baseline row missing from the bench is a regression (a mode silently
 stopped running); a baseline metric missing from its row likewise. New
-rows/metrics are informational until ``--update-baseline`` admits them.
-
-``--append-history FILE`` appends one JSONL entry — git sha, UTC
-timestamp, and the full record set (each row carries its seed) — so
-``BENCH_history.jsonl`` accumulates the cross-PR trajectory.
+rows/metrics are informational until ``--update-baseline`` admits them
+(wall-clock metrics are left out of the baseline it writes).
 
 Usage (the CI step, scripts/ci_fast.sh):
 
-    python scripts/perf_gate.py --bench BENCH_serving.json \
-        --baseline BENCH_baseline.json --smoke \
-        --append-history BENCH_history.jsonl
+    python scripts/perf_gate.py \
+        --bench benchmarks/artifacts/BENCH_serving.json \
+        --baseline BENCH_baseline.json
 """
 from __future__ import annotations
 
 import argparse
 import json
 import os
-import subprocess
+import re
 import sys
-import time
 from typing import Any, Dict, List, Tuple
 
-# default (full-run) budgets; --smoke loosens the timing side for the
-# reduced-size CI rows, where constant costs dominate and wall noise on
-# a shared container is large
 DEFAULT_TOLERANCE = 0.50          # lower-better metrics may grow 50%
 DEFAULT_QUALITY_TOLERANCE = 0.05  # higher-better metrics may drop 5%
-SMOKE_TOLERANCE = 1.50
-SMOKE_QUALITY_TOLERANCE = 0.30
 
-# speedup ratios cancel machine noise; a baseline at/above the margin
-# claims a real win, and such a row dropping below the floor means the
-# fast path measured slower than its own in-run baseline — gated in
-# every mode, independent of the relative budgets above
-SPEEDUP_PARITY_MARGIN = 1.10
-SPEEDUP_PARITY_FLOOR = 1.0
-
+# metrics read off the host's wall clock: never gated
+WALL_CLOCK = re.compile(r"us|(\w+_)?req_s|(\w+_)?tok_s|speedup_\w+"
+                        r"|vs_unsharded")
 HIGHER_BETTER = {
-    "req_s", "admit_req_s", "decode_tok_s", "delivered_under_slo",
-    "prefix_hit_rate", "jain", "served", "acceptance_rate",
-    "tokens_per_step", "kv_bytes_saved", "prefill_flops_saved",
+    "delivered_under_slo", "prefix_hit_rate", "jain", "served",
+    "acceptance_rate", "tokens_per_step", "kv_bytes_saved",
+    "prefill_flops_saved",
 }
-LOWER_BETTER = {"profile_overhead"}
 ZERO_TOLERANCE = {"page_leaks"}
 IGNORED = {
     "seed", "uavs", "frames_per_uav", "slo_s", "duration_s", "offered",
@@ -89,9 +70,7 @@ IGNORED = {
     "tokens_replayed", "downshifts", "flight_dumps",
     "deadline_cancelled", "inflight_cancelled", "stage_faults",
     "blackouts_terminal", "cloud_errors_terminal", "kv_pages_peak",
-    "compile_events", "device_events", "profiled_stage_calls",
-    "ledger_flops_total", "ledger_energy_j_total",
-    "decode_roofline_frac", "shard_imbalance",
+    "shard_imbalance",
 }
 
 
@@ -99,12 +78,11 @@ def classify(metric: str) -> str:
     """'higher' | 'lower' | 'zero' | 'ignore' for one metric name."""
     if metric in ZERO_TOLERANCE:
         return "zero"
-    if metric in IGNORED:
+    if metric in IGNORED or WALL_CLOCK.fullmatch(metric):
         return "ignore"
-    if metric in HIGHER_BETTER or metric.startswith("speedup_"):
+    if metric in HIGHER_BETTER:
         return "higher"
-    if metric in LOWER_BETTER or metric == "us" \
-            or metric.endswith("_s") or metric.endswith("_us"):
+    if metric.endswith("_s") or metric.endswith("_us"):
         return "lower"
     return "ignore"
 
@@ -156,22 +134,12 @@ def compare(bench: Dict[str, Dict[str, Any]],
                     regressions.append(
                         f"{name}.{metric}: {new:g} > {old:g} "
                         f"(+{tolerance:.0%} budget -> {limit:g})")
-            else:   # higher-better; speedups ride the time tolerance
-                is_speedup = metric.startswith("speedup_")
-                tol = tolerance if is_speedup else quality_tolerance
-                limit = old * (1.0 - tol)
+            else:   # higher-better
+                limit = old * (1.0 - quality_tolerance)
                 if new < limit:
                     regressions.append(
                         f"{name}.{metric}: {new:g} < {old:g} "
-                        f"(-{tol:.0%} budget -> {limit:g})")
-                elif is_speedup and old >= SPEEDUP_PARITY_MARGIN \
-                        and new < SPEEDUP_PARITY_FLOOR:
-                    regressions.append(
-                        f"{name}.{metric}: {new:g} fell below parity "
-                        f"(baseline {old:g} claimed a >="
-                        f"{SPEEDUP_PARITY_MARGIN:g}x win; the "
-                        f"accelerated path now measures slower than "
-                        f"its in-run baseline)")
+                        f"(-{quality_tolerance:.0%} budget -> {limit:g})")
         for metric in sorted(set(row) - set(base_row)):
             if isinstance(row[metric], (int, float)) \
                     and classify(metric) != "ignore":
@@ -182,63 +150,33 @@ def compare(bench: Dict[str, Dict[str, Any]],
     return regressions, infos
 
 
-def git_sha(repo_dir: str) -> str:
-    try:
-        return subprocess.run(
-            ["git", "rev-parse", "HEAD"], cwd=repo_dir,
-            capture_output=True, text=True, check=True,
-        ).stdout.strip()
-    except (OSError, subprocess.CalledProcessError):
-        return "unknown"
-
-
-def append_history(path: str, bench: Dict[str, Dict[str, Any]],
-                   sha: str) -> None:
-    entry = {
-        "sha": sha,
-        "time_utc": time.strftime("%Y-%m-%dT%H:%M:%SZ", time.gmtime()),
-        "records": bench,
-    }
-    with open(path, "a") as f:
-        f.write(json.dumps(entry, sort_keys=True) + "\n")
-
-
 def main(argv=None) -> int:
     here = os.path.dirname(os.path.abspath(__file__))
     repo = os.path.dirname(here)
     ap = argparse.ArgumentParser(
         prog="python scripts/perf_gate.py",
-        description="diff BENCH_serving.json against the committed "
-                    "baseline; exit 1 on regression")
+        description="diff the serving bench's artifact against the "
+                    "committed baseline; exit 1 on regression")
     ap.add_argument("--bench",
-                    default=os.path.join(repo, "BENCH_serving.json"))
+                    default=os.path.join(repo, "benchmarks", "artifacts",
+                                         "BENCH_serving.json"))
     ap.add_argument("--baseline",
                     default=os.path.join(repo, "BENCH_baseline.json"))
-    ap.add_argument("--tolerance", type=float, default=None,
-                    help="relative budget for lower-better (timing) "
-                         f"metrics (default {DEFAULT_TOLERANCE})")
-    ap.add_argument("--quality-tolerance", type=float, default=None,
+    ap.add_argument("--tolerance", type=float, default=DEFAULT_TOLERANCE,
+                    help="relative budget for lower-better (mission-"
+                         f"clock latency) metrics (default "
+                         f"{DEFAULT_TOLERANCE})")
+    ap.add_argument("--quality-tolerance", type=float,
+                    default=DEFAULT_QUALITY_TOLERANCE,
                     help="relative budget for higher-better metrics "
                          f"(default {DEFAULT_QUALITY_TOLERANCE})")
-    ap.add_argument("--smoke", action="store_true",
-                    help="CI smoke budgets: looser timing tolerance "
-                         "for reduced-size rows on shared runners")
     ap.add_argument("--json", action="store_true",
                     help="machine-readable report on stdout")
     ap.add_argument("--update-baseline", action="store_true",
                     help="rewrite the baseline from the current bench "
                          "(run after an intentional perf change)")
-    ap.add_argument("--append-history", metavar="FILE", default=None,
-                    help="append a sha-stamped JSONL entry with the "
-                         "full record set")
     args = ap.parse_args(argv)
-
-    tolerance = args.tolerance if args.tolerance is not None else (
-        SMOKE_TOLERANCE if args.smoke else DEFAULT_TOLERANCE)
-    quality = args.quality_tolerance \
-        if args.quality_tolerance is not None else (
-            SMOKE_QUALITY_TOLERANCE if args.smoke
-            else DEFAULT_QUALITY_TOLERANCE)
+    tolerance, quality = args.tolerance, args.quality_tolerance
 
     try:
         bench = load_bench(args.bench)
@@ -247,14 +185,15 @@ def main(argv=None) -> int:
         return 2
 
     if args.update_baseline:
+        records = {name: {m: v for m, v in row.items()
+                          if not WALL_CLOCK.fullmatch(m)}
+                   for name, row in bench.items()}
         with open(args.baseline, "w") as f:
             json.dump({"benchmark": "BENCH_baseline",
-                       "records": bench}, f, indent=2, sort_keys=True)
+                       "records": records}, f, indent=2, sort_keys=True)
             f.write("\n")
         print(f"perf_gate: baseline updated from {args.bench} "
               f"({len(bench)} rows)")
-        if args.append_history:
-            append_history(args.append_history, bench, git_sha(repo))
         return 0
 
     try:
@@ -264,8 +203,6 @@ def main(argv=None) -> int:
         return 2
 
     regressions, infos = compare(bench, baseline, tolerance, quality)
-    if args.append_history:
-        append_history(args.append_history, bench, git_sha(repo))
 
     if args.json:
         print(json.dumps({
@@ -286,7 +223,7 @@ def main(argv=None) -> int:
                   f"{len(baseline)} baselined rows")
         else:
             print(f"perf_gate: clean ({n_rows}/{len(baseline)} "
-                  f"baselined rows checked, +{tolerance:.0%} time / "
+                  f"baselined rows checked, +{tolerance:.0%} latency / "
                   f"-{quality:.0%} quality budgets)")
     return 1 if regressions else 0
 

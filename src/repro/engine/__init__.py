@@ -13,10 +13,6 @@
   observability — Tracer (per-request spans -> Perfetto JSON),
                 MetricsRegistry (counters/gauges/log-bucket histograms),
                 FlightRecorder (bounded ring, crash dumps)
-  profiler    — StageProfiler (device-level stage timing + Perfetto
-                device track), CompileObservatory (graded compile-event
-                visibility), CloudCostModel (per-request FLOPs/bytes/
-                joules ledger)
   engine      — AveryEngine + OperatorSession
 
 All entry points (serving launcher, mission simulator, fleet runtime,
@@ -36,8 +32,6 @@ from repro.engine.policy import (AdaptivePolicy, BestEffortPolicy,
                                  ControlPolicy, RetryPolicy,
                                  StaticTierPolicy, TierDecision,
                                  policy_from_mode)
-from repro.engine.profiler import (CloudCostModel, CompileObservatory,
-                                   StageProfiler)
 from repro.engine.scheduler import (QOS_LATENCY, QOS_THROUGHPUT,
                                     FifoScheduler, QoSScheduler,
                                     jain_index, qos_class)
@@ -58,6 +52,5 @@ __all__ = [
     "Transport", "ChannelTransport", "LoopbackTransport",
     "Tracer", "Span", "RequestTrace", "MetricsRegistry",
     "Counter", "Gauge", "Histogram", "FlightRecorder",
-    "StageProfiler", "CompileObservatory", "CloudCostModel",
     "validate_trace", "validate_traces", "validate_chrome_trace",
 ]

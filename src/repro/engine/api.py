@@ -107,13 +107,6 @@ class Response:
     # hit, when token 0 exists) minus submission, on the mission clock;
     # preemption round-trips don't move it (the first token stands)
     ttft_s: Optional[float] = None
-    # cost/energy ledger (profiled engines only — docs/observability.md
-    # §Profiler): analytic cloud FLOPs/HBM bytes attributed to this
-    # request's prefill + decode steps, and the joules they imply on the
-    # cloud device model. None when the engine runs unprofiled.
-    cloud_flops: Optional[float] = None
-    cloud_hbm_bytes: Optional[float] = None
-    cloud_energy_j: Optional[float] = None
     events: List[StreamEvent] = field(default_factory=list)
 
     @property

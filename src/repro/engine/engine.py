@@ -131,8 +131,7 @@ class AveryEngine:
                  trace: Any = False,
                  flight_events: int = 256,
                  flight_dir: Optional[str] = None,
-                 wallclock: Optional[Callable[[], float]] = None,
-                 profile: Any = False):
+                 wallclock: Optional[Callable[[], float]] = None):
         """``speculative`` (in-flight batching only): ``True`` enables
         Context-stream draft + paged multi-token verify with defaults,
         an int sets ``draft_tokens``, a ``SpeculativeConfig`` sets
@@ -181,18 +180,7 @@ class AveryEngine:
         ``wallclock`` injects a wall-time source (pass
         ``time.perf_counter``; engine code must not read the wall
         clock itself — averylint AV502) to fill the wall decode/verify
-        step histograms.
-
-        ``profile`` (``True`` or a configured
-        :class:`~repro.engine.profiler.StageProfiler`) adds device-level
-        observability on top: every jitted executor stage call is
-        block-until-ready wall-timed into per-(stage, tier, bucket)
-        histograms, compile events are recorded per jit root (the
-        compile observatory), per-request FLOPs/HBM-bytes/joules ride
-        the responses (the cost ledger), and ``dump_trace`` gains a
-        device track (pid 3). Off by default — zero residue when off;
-        ``profile=True`` requires ``wallclock`` (the profiler times wall
-        seconds and engine code never reads the wall clock itself)."""
+        step histograms."""
         if batching not in BATCHING_MODES:
             raise ValueError(f"batching must be one of {BATCHING_MODES}")
         self.lut = lut
@@ -211,18 +199,6 @@ class AveryEngine:
             if not isinstance(executor, ShardedServingContext):
                 executor = ShardedServingContext(executor, mesh)
         self.mesh = mesh
-        # device-level profiling: resolve the knob, then wrap the
-        # executor so every jitted stage call is wall-timed (the wrap
-        # sits outermost — mesh context and fault injectors included)
-        self.profiler = self._resolve_profiler(profile, wallclock)
-        self.cost_model = None
-        if self.profiler is not None:
-            pcfg = getattr(executor, "pcfg", None)
-            if pcfg is not None:
-                from repro.engine.profiler import CloudCostModel
-                self.cost_model = CloudCostModel(pcfg)
-            if executor is not None:
-                executor = self.profiler.wrap(executor)
         self.executor = executor
         self.transport: Transport = transport or LoopbackTransport()
         self.policy: ControlPolicy = policy or AdaptivePolicy()
@@ -296,24 +272,6 @@ class AveryEngine:
         bind = getattr(self.scheduler_proto, "bind_metrics", None)
         if bind is not None:
             bind(self.metrics)
-        if self.profiler is not None:
-            # bind the mission clock, jit-root census, and flight
-            # recorder now that they all exist
-            self.profiler.attach(self)
-
-    @staticmethod
-    def _resolve_profiler(profile: Any, wallclock):
-        from repro.engine.profiler import StageProfiler
-        if isinstance(profile, StageProfiler):
-            return profile
-        if not profile:
-            return None
-        if wallclock is None:
-            raise ValueError(
-                "profile=True needs wallclock= (the profiler measures "
-                "wall seconds; engine code never reads the wall clock "
-                "itself — pass time.perf_counter)")
-        return StageProfiler(wallclock)
 
     # ---- counters (registry-backed; n_* is the legacy read surface) ----
 
@@ -770,8 +728,7 @@ class AveryEngine:
                     scheduler=self.scheduler_proto.spawn(),
                     clock=lambda: self._now,
                     tracer=self.tracer, metrics=self.metrics,
-                    wallclock=self._wallclock,
-                    profiler=self.profiler, cost=self.cost_model)
+                    wallclock=self._wallclock)
                 san = self._recompile_sanitizer
                 if san is not None and san.armed_at is not None:
                     san.adopt()   # its shared jits' traces are not new
@@ -867,19 +824,6 @@ class AveryEngine:
         tft = out.get("t_first_token")
         if tft is not None:
             resp.ttft_s = max(0.0, tft - fut.request.time_s)
-        flops = out.get("cloud_flops")
-        if flops is not None:
-            # the cost ledger (profiled engines only): analytic
-            # FLOPs/HBM-bytes accumulated per slot by the decoder,
-            # joules from the cloud device's power envelope
-            resp.cloud_flops = flops
-            resp.cloud_hbm_bytes = out.get("cloud_hbm_bytes", 0.0)
-            if self.cost_model is not None:
-                resp.cloud_energy_j = self.cost_model.energy_j(flops)
-            if self.profiler is not None:
-                self.profiler.note_ledger(
-                    resp.cloud_flops, resp.cloud_hbm_bytes or 0.0,
-                    resp.cloud_energy_j or 0.0)
         self._observe_served(fut, resp)
         fut.set_result(resp)
         self._bump("completed")
@@ -1019,21 +963,8 @@ class AveryEngine:
     def dump_trace(self, path: str) -> str:
         """Write every recorded request trace as Chrome/Perfetto
         ``trace_event`` JSON (open at https://ui.perfetto.dev). Tracks:
-        one per operator (pid 1), one per decode slot (pid 2), and —
-        with profiling on — one per device stage (pid 3)."""
-        if self.profiler is None:
-            return self.tracer.dump(path)
-        import json
-        import os
-        doc = self.tracer.to_chrome()
-        doc["traceEvents"] = (doc["traceEvents"]
-                              + self.profiler.chrome_events())
-        d = os.path.dirname(path)
-        if d:
-            os.makedirs(d, exist_ok=True)
-        with open(path, "w") as f:
-            json.dump(doc, f)
-        return path
+        one per operator (pid 1) and one per decode slot (pid 2)."""
+        return self.tracer.dump(path)
 
     def dump_flight(self, path: str, reason: str = "manual"
                     ) -> Optional[str]:
@@ -1287,9 +1218,4 @@ class AveryEngine:
         out["decode_step_p99_s"] = decode.p99
         out["flight_events"] = len(self.flight)
         out["flight_dumps"] = self.flight.n_dumps
-        # device-level profiler summary (docs/observability.md §Profiler):
-        # only present when profiling was requested, so the default stats
-        # surface is byte-identical with the profiler off.
-        if self.profiler is not None:
-            out.update(self.profiler.stats_block())
         return out

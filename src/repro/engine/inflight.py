@@ -166,8 +166,6 @@ class _SlotState:
     batch_acc: int = 0                # sum of co-active slots over steps
     replay: Optional[Deque[int]] = None  # parked tokens to re-decode
     t_admit: float = 0.0              # this residency segment's start
-    flops: float = 0.0                # attributed cloud FLOPs (cost ledger)
-    hbm_bytes: float = 0.0            # attributed HBM traffic (cost ledger)
 
 
 class InflightDecoder:
@@ -190,19 +188,12 @@ class InflightDecoder:
                  clock: Optional[Callable[[], float]] = None,
                  tracer: Optional[Tracer] = None,
                  metrics: Optional[Any] = None,
-                 wallclock: Optional[Callable[[], float]] = None,
-                 profiler: Optional[Any] = None,
-                 cost: Optional[Any] = None):
+                 wallclock: Optional[Callable[[], float]] = None):
         self.executor = executor
         # the step's greedy pick, run on the logits the stage returned so
         # only ids come back to the host; an attribute, so the recompile
         # census (``analysis.sanitizers``) walks it with the decoder
         self.greedy_pick = _greedy_pick_jit()
-        # device-level observability (engine.profiler): the profiler
-        # wraps lazily built draft models; the cost model attributes
-        # analytic FLOPs/HBM bytes to each request as it decodes
-        self._profiler = profiler
-        self._cost = cost
         # observability (engine.observability): the engine threads its
         # tracer/registry through; a standalone decoder records nothing
         self.tracer = tracer if tracer is not None else Tracer()
@@ -495,10 +486,6 @@ class InflightDecoder:
             joined_step=self.step_idx, prefix_ids=entry.page_ids,
             private_ids=private, prefix_hit=hit,
             speculative=speculative)
-        if self._cost is not None and not hit:
-            # a prefix hit rides cached pages: only the miss pays (and is
-            # charged for) the full-sequence prefill
-            st.flops = self._cost.prefill_flops(self.prefix_len)
         if item.resume_tokens:
             # a parked victim resumes from its prefix: token 0 re-emerges
             # from the (cached or re-prefilled) prefix logits, the rest
@@ -531,7 +518,7 @@ class InflightDecoder:
 
     def _make_draft(self) -> DraftModel:
         cfg = self.spec
-        draft = DraftModel(
+        return DraftModel(
             cfg.draft_params or self.executor.params,
             cfg.draft_pcfg or self.executor.pcfg,
             slots=self.slots, prefix_len=self.prefix_len,
@@ -542,9 +529,6 @@ class InflightDecoder:
             # sharded serving context: draft stages jitted with mesh
             # shardings so the draft rides the same tensor parallelism
             fns_factory=getattr(self.executor, "draft_fns", None))
-        if self._profiler is not None:
-            draft = self._profiler.wrap_draft(draft)
-        return draft
 
     # ---- the lockstep decode step ----
 
@@ -623,9 +607,6 @@ class InflightDecoder:
                 st.batch_acc += live
                 # one fed token attending st.pos + 1 cached positions
                 self.totals.attended_positions += st.pos + 1
-                if self._cost is not None:
-                    st.flops += self._cost.token_flops(st.pos + 1)
-                    st.hbm_bytes += self._cost.token_hbm_bytes(st.pos + 1)
                 if self.tracer.enabled:
                     self.tracer.point(st.req.seq_id, "decode_step", now,
                                       slot=s, step=self.step_idx)
@@ -713,15 +694,6 @@ class InflightDecoder:
                 c = int(clens[s])
                 self.totals.attended_positions += \
                     c * (st.pos + 1) + c * (c - 1) // 2
-                if self._cost is not None:
-                    # every fed chunk token costs device compute whether
-                    # or not its draft is accepted — rejected drafts are
-                    # real FLOPs, which is exactly what the ledger should
-                    # show
-                    for i in range(c):
-                        st.flops += self._cost.token_flops(st.pos + i + 1)
-                        st.hbm_bytes += self._cost.token_hbm_bytes(
-                            st.pos + i + 1)
                 # greedy[i]: the serving model's own pick after chunk
                 # token i
                 greedy = ids[s, :1 + j]
@@ -857,9 +829,6 @@ class InflightDecoder:
                 "preemptions": st.req.resumes,
                 "queue_wait": st.req.queue_wait,
                 "t_first_token": st.req.t_first_token,
-                "cloud_flops": st.flops if self._cost is not None else None,
-                "cloud_hbm_bytes": st.hbm_bytes
-                if self._cost is not None else None,
             })
             if st.req.resumes:
                 self.scheduler.note_resumed_served()

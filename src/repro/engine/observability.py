@@ -45,9 +45,7 @@ from typing import Any, Dict, List, Optional
 # vocabulary; point events are open-ended)
 PHASE_SPANS = ("edge_encode", "transmit", "queue", "prefill",
                "prefix_hit", "decode")
-# Chrome-export track families: pid 1 = operators, pid 2 = decode
-# slots, pid 3 = device stages (the StageProfiler's wall-clock view)
-DEVICE_TRACK_PID = 3
+# Chrome-export track families: pid 1 = operators, pid 2 = decode slots
 _EPS = 1e-9
 
 
@@ -285,16 +283,6 @@ def validate_chrome_trace(doc: Dict[str, Any]) -> List[str]:
     rebuilt: Dict[int, RequestTrace] = {}
     for ev in events:
         ph = ev.get("ph")
-        if ph in ("X", "i") and ev.get("pid") == DEVICE_TRACK_PID:
-            # device-stage events are batch-level (no rid); check the
-            # timeline shape instead of the request lifecycle
-            if not isinstance(ev.get("ts"), (int, float)):
-                return [f"device event {ev.get('name')!r} has no "
-                        f"numeric ts"]
-            if ph == "X" and float(ev.get("dur", 0.0)) < 0.0:
-                return [f"device span {ev.get('name')!r} has negative "
-                        f"dur"]
-            continue
         if ph not in ("X", "i") or ev.get("pid") != 1:
             continue
         rid = ev.get("args", {}).get("rid")

@@ -13,8 +13,6 @@ Constants:
     3.12 J / 0.232 s ≈ 13.5 W — we use 2 TFLOP/s and 15 W. With these,
     our analytic model lands within ~10% of every Fig. 8 point we can
     check (see EXPERIMENTS.md §Paper-claims).
-  * Cloud/TPU target: TPU v5e — 197 TFLOP/s bf16, 819 GB/s HBM,
-    ~50 GB/s/link ICI (the roofline constants).
   * Radio: long-range uplink ~ 120 nJ/bit transmit energy.
 """
 from __future__ import annotations
@@ -27,10 +25,6 @@ from repro.models.config import ModelConfig
 JETSON_FLOPS = 1.28e12          # effective fp16 FLOP/s (Fig. 8 calibrated:
                                 # split@1 edge latency == 0.2318 s)
 JETSON_POWER_W = 15.0           # average active power in MODE_30W_ALL
-TPU_V5E_FLOPS = 197e12          # bf16 FLOP/s per chip
-TPU_V5E_HBM_BPS = 819e9         # bytes/s
-TPU_V5E_ICI_BPS = 50e9          # bytes/s per link
-TPU_V5E_POWER_W = 170.0         # nameplate per-chip power envelope
 RADIO_J_PER_BIT = 120e-9
 
 
@@ -47,26 +41,6 @@ class EdgeDevice:
 
     def tx_energy_j(self, payload_bytes: float) -> float:
         return payload_bytes * 8 * RADIO_J_PER_BIT
-
-
-@dataclass(frozen=True)
-class CloudDevice:
-    """The cloud serving chip's roofline constants (TPU v5e defaults).
-    ``roofline_s`` is the lower bound a stage's measured wall time is
-    compared against: max of compute-bound and bandwidth-bound time."""
-    flops_per_sec: float = TPU_V5E_FLOPS
-    hbm_bytes_per_sec: float = TPU_V5E_HBM_BPS
-    power_watts: float = TPU_V5E_POWER_W
-
-    def latency_s(self, flops: float) -> float:
-        return flops / self.flops_per_sec
-
-    def roofline_s(self, flops: float, hbm_bytes: float) -> float:
-        return max(flops / self.flops_per_sec,
-                   hbm_bytes / self.hbm_bytes_per_sec)
-
-    def compute_energy_j(self, flops: float) -> float:
-        return self.latency_s(flops) * self.power_watts
 
 
 # ---------------------------------------------------------------------------
@@ -94,28 +68,6 @@ def encoder_flops(cfg: ModelConfig, seq: int, num_blocks: int = -1) -> float:
 
 def bottleneck_flops(d: int, rank: int, seq: int) -> float:
     return float(2 * seq * d * rank)
-
-
-def decode_token_flops(cfg: ModelConfig, ctx_len: int) -> float:
-    """One autoregressive decode step (single token, KV cache of
-    ``ctx_len`` attended positions), per batch row: qkvo + mlp are the
-    seq=1 slice of :func:`attn_block_flops`; scores attend the full
-    cached context."""
-    d, heads, head_dim = cfg.d_model, cfg.num_heads, cfg.resolved_head_dim
-    qkvo = 2 * d * (heads * head_dim + 2 * cfg.num_kv_heads * head_dim
-                    + heads * head_dim)
-    scores = 2 * ctx_len * heads * head_dim * 2     # QK^T and PV
-    mlp = 2 * d * cfg.d_ff * (3 if cfg.gated_mlp else 2)
-    return float(cfg.num_layers * (qkvo + scores + mlp))
-
-
-def decode_token_hbm_bytes(cfg: ModelConfig, ctx_len: int,
-                           dtype_bytes: int = 2) -> float:
-    """Dominant HBM traffic of one decode step, per batch row: the K and
-    V cache reads over ``ctx_len`` positions in every layer (weight
-    reads amortise over the batch; activations are tiny at seq=1)."""
-    return float(2 * cfg.num_layers * ctx_len * cfg.num_kv_heads
-                 * cfg.resolved_head_dim * dtype_bytes)
 
 
 def patch_embed_flops(d: int, patch: int, seq: int, in_ch: int = 3) -> float:

@@ -1,7 +1,7 @@
-"""The perf-regression gate: metric classification, the diff budgets
-(direction + tolerance, zero-tolerance leaks, missing rows/metrics),
-CLI exit codes, the baseline update round-trip, and the sha-stamped
-history log."""
+"""The perf-regression gate: metric classification (wall-clock metrics
+never gate), the diff budgets (direction + tolerance, zero-tolerance
+leaks, missing rows/metrics), CLI exit codes, the baseline update
+round-trip, and the bench artifact writer."""
 import copy
 import importlib.util
 import json
@@ -39,10 +39,10 @@ def _dump(path, records):
 
 
 @pytest.mark.parametrize("metric,kind", [
-    ("us", "lower"), ("ttft_p50_s", "lower"), ("wait_p95_us", "lower"),
-    ("profile_overhead", "lower"),
-    ("req_s", "higher"), ("delivered_under_slo", "higher"),
-    ("jain", "higher"), ("speedup_vs_paged", "higher"),
+    ("us", "ignore"), ("ttft_p50_s", "lower"), ("wait_p95_us", "lower"),
+    ("admit_req_s", "ignore"),
+    ("req_s", "ignore"), ("delivered_under_slo", "higher"),
+    ("jain", "higher"), ("speedup_vs_paged", "ignore"),
     ("page_leaks", "zero"),
     ("seed", "ignore"), ("note", "ignore"), ("compile_events", "ignore"),
     ("ledger_flops_total", "ignore"), ("some_unknown_counter", "ignore"),
@@ -61,11 +61,11 @@ def test_identical_bench_is_clean():
 
 def test_timing_regression_beyond_tolerance():
     bench = copy.deepcopy(BENCH)
-    bench["serving/alpha"]["us"] = 10000.0          # 10x the baseline
+    bench["serving/alpha"]["ttft_p99_s"] = 5.0      # 10x the baseline
     regs, _ = perf_gate.compare(bench, BENCH, 0.5, 0.05)
-    assert len(regs) == 1 and "serving/alpha.us" in regs[0]
+    assert len(regs) == 1 and "serving/alpha.ttft_p99_s" in regs[0]
     # ...but within the budget it's noise, not a regression
-    bench["serving/alpha"]["us"] = 1400.0           # +40% < +50%
+    bench["serving/alpha"]["ttft_p99_s"] = 0.7      # +40% < +50%
     regs, _ = perf_gate.compare(bench, BENCH, 0.5, 0.05)
     assert regs == []
 
@@ -79,46 +79,20 @@ def test_quality_drop_beyond_tolerance():
     assert "serving/alpha.delivered_under_slo" in regs[0]
 
 
-def test_speedup_rides_the_time_tolerance():
-    bench = copy.deepcopy(BENCH)
-    bench["serving/beta"]["speedup_vs_paged"] = 1.0  # -29%: inside +-50%
-    regs, _ = perf_gate.compare(bench, BENCH, 0.5, 0.05)
-    assert regs == []
-    bench["serving/beta"]["speedup_vs_paged"] = 0.6  # -57%: beyond
-    regs, _ = perf_gate.compare(bench, BENCH, 0.5, 0.05)
-    assert len(regs) == 1 and "speedup_vs_paged" in regs[0]
-
-
-def test_speedup_parity_floor_gates_hard():
-    # baseline claims a 1.4x win: a recorded value below 1.0 means the
-    # fast path measured slower than its own in-run baseline — gated
-    # even inside the loose smoke time tolerance (which would otherwise
-    # admit anything down to 1.4 * (1 - 1.5) < 0)
-    bench = copy.deepcopy(BENCH)
-    bench["serving/beta"]["speedup_vs_paged"] = 0.95
-    regs, _ = perf_gate.compare(bench, BENCH, 1.5, 0.30)
-    assert len(regs) == 1 and "below parity" in regs[0]
-    # at parity or above, the relative budget alone governs
-    bench["serving/beta"]["speedup_vs_paged"] = 1.0
-    regs, _ = perf_gate.compare(bench, BENCH, 1.5, 0.30)
-    assert regs == []
-
-
-def test_near_parity_speedup_baseline_skips_the_floor():
-    # a row whose baseline never claimed a material win (the
-    # CPU-container spec-decode row sits near 1.0 by design: the draft
-    # shares the target's geometry) must not flap CI on noise dipping
-    # below 1.0...
+@pytest.mark.parametrize("metric", [
+    "us", "req_s", "admit_req_s", "tok_s", "decode_tok_s",
+    "speedup_vs_paged", "speedup_vs_no_prefix_reuse", "vs_unsharded",
+])
+def test_wall_clock_metrics_never_gate(metric):
+    """The bench runs on the CPU: a wall-clock metric moved 10x either
+    way (the wrong one included) is not a regression."""
     base = copy.deepcopy(BENCH)
-    base["serving/beta"]["speedup_vs_paged"] = 1.01
-    bench = copy.deepcopy(base)
-    bench["serving/beta"]["speedup_vs_paged"] = 0.79
-    regs, _ = perf_gate.compare(bench, base, 1.5, 0.30)
-    assert regs == []
-    # ...though the relative time budget still bounds the fall
-    bench["serving/beta"]["speedup_vs_paged"] = 0.2
-    regs, _ = perf_gate.compare(bench, base, 0.5, 0.05)
-    assert len(regs) == 1 and "speedup_vs_paged" in regs[0]
+    base["serving/alpha"][metric] = 100.0
+    for factor in (10.0, 0.1):
+        bench = copy.deepcopy(base)
+        bench["serving/alpha"][metric] = 100.0 * factor
+        regs, _ = perf_gate.compare(bench, base, 0.5, 0.05)
+        assert regs == [], (factor, regs)
 
 
 def test_page_leak_is_zero_tolerance():
@@ -152,7 +126,7 @@ def test_new_rows_and_metrics_are_informational():
     assert any("thr_p50_s: new metric" in i for i in infos)
 
 
-# ---- the CLI: exit codes, baseline round-trip, history ----
+# ---- the CLI: exit codes, baseline round-trip, JSON report ----
 
 
 def test_main_exit_codes(tmp_path, capsys):
@@ -161,7 +135,7 @@ def test_main_exit_codes(tmp_path, capsys):
     assert perf_gate.main(["--bench", bench, "--baseline", base]) == 0
     assert "clean" in capsys.readouterr().out
     worse = copy.deepcopy(BENCH)
-    worse["serving/alpha"]["us"] = 10000.0
+    worse["serving/alpha"]["ttft_p99_s"] = 5.0
     bad = _dump(tmp_path / "bad.json", worse)
     assert perf_gate.main(["--bench", bad, "--baseline", base]) == 1
     assert "REGRESSION" in capsys.readouterr().out
@@ -176,7 +150,7 @@ def test_main_exit_codes(tmp_path, capsys):
 
 def test_update_baseline_roundtrip(tmp_path, capsys):
     worse = copy.deepcopy(BENCH)
-    worse["serving/alpha"]["us"] = 10000.0
+    worse["serving/alpha"]["ttft_p99_s"] = 5.0
     bench = _dump(tmp_path / "bench.json", worse)
     base = str(tmp_path / "base.json")
     # an intentional perf change: admit the new numbers, gate is clean
@@ -184,63 +158,51 @@ def test_update_baseline_roundtrip(tmp_path, capsys):
                            "--update-baseline"]) == 0
     capsys.readouterr()
     assert perf_gate.main(["--bench", bench, "--baseline", base]) == 0
-    written = json.loads(Path(base).read_text())
-    assert written["records"]["serving/alpha"]["us"] == 10000.0
+    written = json.loads(Path(base).read_text())["records"]
+    assert written["serving/alpha"]["ttft_p99_s"] == 5.0
+    # wall-clock metrics never enter the baseline
+    assert "us" not in written["serving/alpha"]
+    assert "speedup_vs_paged" not in written["serving/beta"]
 
 
 def test_json_report_and_history(tmp_path, capsys):
     bench = _dump(tmp_path / "bench.json", BENCH)
     base = _dump(tmp_path / "base.json", BENCH)
-    hist = tmp_path / "hist.jsonl"
     assert perf_gate.main(["--bench", bench, "--baseline", base,
-                           "--json", "--append-history", str(hist)]) == 0
+                           "--json"]) == 0
     report = json.loads(capsys.readouterr().out)
     assert report["ok"] is True and report["regressions"] == []
-    # history appends one parseable sha-stamped entry per run
-    assert perf_gate.main(["--bench", bench, "--baseline", base,
-                           "--append-history", str(hist)]) == 0
-    capsys.readouterr()
-    lines = hist.read_text().splitlines()
-    assert len(lines) == 2
-    for line in lines:
-        entry = json.loads(line)
-        assert entry["sha"] and entry["time_utc"]
-        assert entry["records"] == BENCH
+    assert sorted(tmp_path.iterdir()) == [tmp_path / "base.json",
+                                          tmp_path / "bench.json"]
 
 
-def test_write_bench_json_seeds_merge_from_committed_mirror(tmp_path,
-                                                            monkeypatch):
-    """A fresh checkout has no ``benchmarks/artifacts/`` bench file but
-    does have the committed root mirror: a partial (smoke) run must
-    merge into the tracked trajectory, not clobber it down to its own
-    rows — the gate treats a vanished row as a regression, so the
-    merge base is load-bearing for CI on clean clones."""
+def test_write_bench_json_writes_only_its_artifact(tmp_path, monkeypatch):
+    """The bench artifact lives under ``benchmarks/artifacts/`` (git
+    ignores it): rows merge into it by name, and nothing else — no
+    tracked file at the repo root — is written."""
     spec = importlib.util.spec_from_file_location(
         "bench_common", REPO / "benchmarks" / "common.py")
     common = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(common)
     art = tmp_path / "repo" / "benchmarks" / "artifacts"
     monkeypatch.setattr(common, "ART", str(art))
-    mirror = tmp_path / "repo" / "BENCH_serving.json"
-    mirror.parent.mkdir(parents=True)
-    _dump(mirror, {"serving/full": {"us": 9.0, "req_s": 100.0}})
+    path = common.write_bench_json(["serving/full,9,req_s=100.0"])
+    assert Path(path) == art / "BENCH_serving.json"
     common.write_bench_json(["serving/smoke,5,req_s=42.0"])
-    merged = json.loads(mirror.read_text())["records"]
-    assert set(merged) == {"serving/full", "serving/smoke"}
-    assert merged["serving/smoke"]["req_s"] == 42.0
-    # once the artifact exists it is the merge base (and wins over the
-    # now-stale mirror): a second run updates its row in place
     common.write_bench_json(["serving/smoke,5,req_s=43.0"])
-    merged = json.loads(mirror.read_text())["records"]
-    assert set(merged) == {"serving/full", "serving/smoke"}
-    assert merged["serving/smoke"]["req_s"] == 43.0
+    records = json.loads(Path(path).read_text())["records"]
+    assert set(records) == {"serving/full", "serving/smoke"}
+    assert records["serving/full"] == {"us": 9.0, "req_s": 100.0}
+    assert records["serving/smoke"]["req_s"] == 43.0
+    written = [p for p in tmp_path.rglob("*") if p.is_file()]
+    assert written == [Path(path)]
 
 
-def test_committed_baseline_gates_committed_bench(capsys):
-    """The repo's own artifacts: the committed bench must pass the
-    committed baseline under the full-run budgets (CI runs the smoke
-    budgets, so this is the stricter check)."""
-    rc = perf_gate.main(["--bench", str(REPO / "BENCH_serving.json"),
-                         "--baseline", str(REPO / "BENCH_baseline.json")])
-    out = capsys.readouterr().out
-    assert rc == 0, f"committed bench regresses committed baseline:\n{out}"
+def test_committed_baseline_holds_no_wall_clock_metric():
+    """The committed baseline gates counts and mission-clock numbers
+    only: no row carries a metric read off the host's wall clock."""
+    baseline = perf_gate.load_bench(str(REPO / "BENCH_baseline.json"))
+    assert baseline
+    clocked = [f"{name}.{m}" for name, row in baseline.items()
+               for m in row if perf_gate.WALL_CLOCK.fullmatch(m)]
+    assert clocked == []

@@ -91,6 +91,37 @@ def test_recompile_sanitizer_detects_churn(cold_executor):
         engine.drain()
 
 
+def test_pool_growth_after_arm_trips_the_budget(cold_executor):
+    """A pool too small for its traffic grows mid-flight; the decode
+    shapes change and the paged stages recompile. Armed after warm-up,
+    the sanitizer turns that churn into ``RecompileBudgetError``."""
+    import jax.numpy as jnp
+
+    from repro.data import floodseg
+    rng = np.random.RandomState(311)
+
+    def submit(engine, i):
+        b = floodseg.make_batch(rng, 1, "segment", augment=False)
+        pkt = cold_executor.edge_insight(jnp.asarray(b["images"]),
+                                         LUT.tiers[0], i, 0.0)
+        return engine.submit_packet(pkt, b["query"], Intent.INSIGHT,
+                                    time_s=float(i),
+                                    session=engine.session(f"uav-{i}"))
+
+    engine = _engine(cold_executor, debug_recompiles=True, kv_pages=2)
+    submit(engine, 0)
+    engine.drain()
+    pages0 = engine.stats["kv_pages_total"]
+    assert engine.arm_sanitizers() > 0
+    # enough distinct prefixes to outgrow the first prefill's capacity
+    # hint: the pool doubles and the paged stages recompile
+    with pytest.raises(RecompileBudgetError):
+        for i in range(1, 8):
+            submit(engine, i)
+        engine.drain()
+    assert engine.stats["kv_pages_total"] > pages0   # the pool really grew
+
+
 def test_jit_roots_discovery(cold_executor):
     """The census walks the executor's fixed jits and its keyed compile
     cache (dict values)."""

@@ -267,10 +267,10 @@ def test_scheduler_generate_mode(executor):
 
 @pytest.mark.slow
 def test_bench_serving_paged_mode_reports_prefix_reuse():
-    """The bench's paged mode must report a prefix-cache hit rate and an
-    admission-throughput speedup from prefix reuse on the repeat-prefix
-    per-UAV workload (>= 2x on the Context stream, whose admission cost
-    is the prefix prefill the store removes)."""
+    """The bench's paged mode must show prefix reuse on the repeat-prefix
+    per-UAV workload by its counts, on both streams: every frame after a
+    UAV's first hits the prefix store (4 frames per UAV: 3 hits of 4),
+    and the hits save prefill FLOPs and prefix KV bytes."""
     import subprocess
     import sys
 
@@ -282,11 +282,12 @@ def test_bench_serving_paged_mode_reports_prefix_reuse():
     rows = [l for l in out.stdout.splitlines()
             if l.startswith("serving/paged_admit_")]
     assert len(rows) == 2
-    ctx_row = next(r for r in rows if "context" in r)
-    fields = dict(f.split("=") for f in ctx_row.split(",")[2].split(";"))
-    assert float(fields["speedup_vs_no_prefix_reuse"].rstrip("x")) >= 2.0
-    assert 0.0 < float(fields["prefix_hit_rate"]) <= 1.0
-    assert float(fields["kv_bytes_saved"]) > 0
+    for kind in ("context", "insight"):
+        row = next(r for r in rows if kind in r)
+        fields = dict(f.split("=") for f in row.split(",")[2].split(";"))
+        assert float(fields["prefix_hit_rate"]) == 0.75, row
+        assert float(fields["prefill_flops_saved"]) > 0, row
+        assert float(fields["kv_bytes_saved"]) > 0, row
 
 
 @pytest.mark.slow
